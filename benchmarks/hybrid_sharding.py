@@ -27,15 +27,11 @@ def run_structural():
     from repro.launch import hlo as H
     from repro.launch.mesh import make_host_mesh
 
-    from repro import compat
-
     cfg = get_reduced("qwen-1.5b")
-    # old XLA aborts on partially-manual SPMD (tensor-parallel auto axis
-    # under the manual FSDP region) — drop to a pure-FSDP mesh there; the
-    # intra- vs inter-pod volume claims only need the pod/data split
-    mesh = (make_host_mesh(data=2, model=2, pod=2)
-            if compat.supports_partial_auto()
-            else make_host_mesh(data=4, model=1, pod=2))
+    # a tensor-parallel axis under the manual FSDP region (partially-manual
+    # SPMD); the intra- vs inter-pod volume claims only need the pod/data
+    # split
+    mesh = make_host_mesh(data=2, model=2, pod=2)
     M = 4  # microbatches: per-layer gathers repeat M times per minibatch
     batch = {
         "tokens": jax.ShapeDtypeStruct((M, 8, 64), jnp.int32),

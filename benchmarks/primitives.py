@@ -21,7 +21,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 from repro.core import odc
 
 
@@ -62,8 +61,8 @@ def run_measured(sizes=(1 << 16, 1 << 20, 1 << 22)):
             ("reduce_scatter", s_coll, P(None), P("x")),
             ("odc_scatter_accumulate", s_odc, P(None), P("x")),
         ]:
-            f = jax.jit(compat.shard_map(inner, mesh=mesh, in_specs=spec_in,
-                                         out_specs=spec_out, check_vma=False))
+            f = jax.jit(jax.shard_map(inner, mesh=mesh, in_specs=spec_in,
+                                      out_specs=spec_out, check_vma=False))
             dt = _time(f, x)
             moved = 4 * per * (n - 1) * n  # bytes on the wire, total
             rows.append({
@@ -125,7 +124,7 @@ def run_overlap_issue(layers=4, per_layer=1 << 18):
     outs = {}
     for name, inner in [("odc_gather_fused_Llayers", fused),
                         ("odc_gather_pipelined_Llayers", pipelined)]:
-        f = jax.jit(compat.shard_map(
+        f = jax.jit(jax.shard_map(
             inner, mesh=mesh, in_specs=P(None, "x"), out_specs=P(None),
             check_vma=False))
         dt = _time(f, x)

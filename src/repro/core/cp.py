@@ -43,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import odc
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attention import (finish_attention,
                                            flash_attention_bwd_ref,
                                            flash_attention_state)
@@ -216,7 +217,7 @@ _ring_attn.defvjp(_ring_attn_fwd, _ring_bwd_impl)
 def ring_attention(q, k, v, *, axis_name="cp", causal=True, window=0,
                    logit_softcap=0.0, q_positions=None, kv_positions=None,
                    q_segment_ids=None, kv_segment_ids=None, blk_q=128,
-                   blk_k=128, scale=None, interpret=True,
+                   blk_k=128, scale=None, interpret=None,
                    gather_impl="jnp", interleave=True):
     """Context-parallel self-attention for one (B, S_loc, H, hd) q shard.
 
@@ -244,7 +245,7 @@ def ring_attention(q, k, v, *, axis_name="cp", causal=True, window=0,
     if kv_segment_ids is None:
         kv_segment_ids = q_segment_ids
     static = (axis_name, bool(causal), int(window), float(logit_softcap),
-              float(scale), int(blk_q), int(blk_k), bool(interpret),
+              float(scale), int(blk_q), int(blk_k), interpret_mode(interpret),
               gather_impl, bool(interleave))
     return _ring_attn(static, q, k, v, q_positions, kv_positions,
                       q_segment_ids, kv_segment_ids)
@@ -315,15 +316,13 @@ def cp_attention_impl(axis_name="cp", *, blk_q=128, blk_k=128,
                 kv_positions=kv_positions, q_segment_ids=q_segment_ids,
                 kv_segment_ids=kv_segment_ids, block_kv=block_kv,
                 scale=scale, interleave=interleave)
-        interp = (jax.default_backend() != "tpu") if interpret is None \
-            else interpret
         return ring_attention(
             q, k, v, axis_name=axis_name, causal=causal, window=int(window),
             logit_softcap=logit_softcap, q_positions=q_positions,
             kv_positions=kv_positions, q_segment_ids=q_segment_ids,
             kv_segment_ids=kv_segment_ids, blk_q=blk_q,
             blk_k=min(blk_k, block_kv) if block_kv else blk_k,
-            scale=scale, interpret=interp, gather_impl=gather_impl,
+            scale=scale, interpret=interpret, gather_impl=gather_impl,
             interleave=interleave)
 
     return impl

@@ -74,7 +74,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
 from repro.optim import AdamWConfig, adamw_init, adamw_update
@@ -567,24 +566,16 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, gcfg: GSPMDConfig,
         if _axes_in_spec(auto):
             # use the context (abstract) mesh: inside shard_map the data
             # axes are Manual and a concrete-mesh NamedSharding would not
-            # match the tracing context.  Old jax has no abstract mesh AND
-            # its XLA hard-crashes (IsManualSubgroup check) on sharding
-            # constraints inside a partially-manual region — the anchor is
-            # a performance hint, so it is skipped there and GSPMD infers
-            # the model-axis sharding on its own.
-            ctx = compat.get_abstract_mesh()
-            if ctx is not None and ctx.shape:
-                leaf = jax.lax.with_sharding_constraint(
-                    leaf, NamedSharding(ctx, auto))
+            # match the tracing context
+            leaf = jax.lax.with_sharding_constraint(
+                leaf, NamedSharding(jax.sharding.get_abstract_mesh(), auto))
         return leaf
 
     def _constrain_auto(leaf, spec):
         auto = _drop_axis(spec, manual)
         if _axes_in_spec(auto):
-            ctx = compat.get_abstract_mesh()
-            if ctx is not None and ctx.shape:
-                leaf = jax.lax.with_sharding_constraint(
-                    leaf, NamedSharding(ctx, auto))
+            leaf = jax.lax.with_sharding_constraint(
+                leaf, NamedSharding(jax.sharding.get_abstract_mesh(), auto))
         return leaf
 
     def gather_full(params_local):
@@ -730,7 +721,7 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, gcfg: GSPMDConfig,
     def step(params, opt_state, batch):
         from repro.models import layers as L
         from repro.models import moe as moe_mod
-        sharded = compat.shard_map(
+        sharded = jax.shard_map(
             grad_minibatch,
             mesh=mesh,
             in_specs=(manual_pspecs, batch_manual_specs(batch)),
@@ -753,49 +744,72 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, gcfg: GSPMDConfig,
     return step
 
 
-def build_train_artifacts(cfg: ModelConfig, mesh: Mesh, gcfg: GSPMDConfig,
-                          batch_shapes, opt_cfg: AdamWConfig = AdamWConfig()):
-    """ShapeDtypeStruct stand-ins + jitted step ready to .lower() — no
-    device allocation (the dry-run path)."""
-    rules = gcfg.rules
+def train_state_shardings(cfg: ModelConfig, mesh: Mesh, gcfg: GSPMDConfig):
+    """(param shardings, AdamW-state shardings): the FSDP layout of the
+    training state, as NamedSharding pytrees."""
     params_shape = jax.eval_shape(
         lambda k: T.init_params(cfg, k, gcfg.param_dtype), jax.random.PRNGKey(0))
     pspecs = train_param_pspecs(cfg, params_shape, gcfg, mesh)
-    params_in = jax.tree.map(
-        lambda s, sp: jax.ShapeDtypeStruct(s.shape, s.dtype,
-                                           sharding=NamedSharding(mesh, sp)),
-        params_shape, pspecs)
-
     opt_shape = jax.eval_shape(adamw_init, params_shape)
     ospecs = opt_pspecs(pspecs, gcfg)
     # hybrid_pod widening can exceed a small dim (e.g. mamba2's 80 ssm
     # heads over pod×data=32) — sanitize against the actual shapes
     ospecs = jax.tree.map(
         lambda s, sp: sanitize_spec(sp, s.shape, mesh), opt_shape, ospecs)
-    opt_in = jax.tree.map(
-        lambda s, sp: jax.ShapeDtypeStruct(s.shape, s.dtype,
-                                           sharding=NamedSharding(mesh, sp)),
-        opt_shape, ospecs)
+    named = lambda sp: NamedSharding(mesh, sp)
+    return jax.tree.map(named, pspecs), jax.tree.map(named, ospecs)
 
+
+def train_batch_shardings(batch, mesh: Mesh, gcfg: GSPMDConfig):
+    """NamedSharding pytree for a (M, B_global, S...) batch: rows over the
+    dp axes, and the sequence dim over the cp axis under comm='cp'."""
     from repro.core import backend as B
     cb, _ = B.resolve(gcfg.comm, gcfg.schedule)
+    rules = gcfg.rules
     da = rules.data if isinstance(rules.data, tuple) else (rules.data,)
     cp_ax = da[-1] if (cb.name == "cp" and len(da) > 1) else None
-    bspecs = batch_pspecs(batch_shapes, rules, cp_axis=cp_ax)
-    batch_in = jax.tree.map(
-        lambda s, sp: jax.ShapeDtypeStruct(s.shape, s.dtype,
-                                           sharding=NamedSharding(mesh, sp)),
-        batch_shapes, bspecs)
+    return jax.tree.map(lambda sp: NamedSharding(mesh, sp),
+                        batch_pspecs(batch, rules, cp_axis=cp_ax))
 
-    step = make_train_step(cfg, mesh, gcfg, opt_cfg)
-    out_shardings = (
-        jax.tree.map(lambda sp: NamedSharding(mesh, sp), pspecs),
-        jax.tree.map(lambda sp: NamedSharding(mesh, sp), ospecs),
-        None,
-    )
-    jitted = jax.jit(step, out_shardings=out_shardings,
-                     donate_argnums=(0, 1))
-    return jitted, (params_in, opt_in, batch_in)
+
+def jit_train_step(cfg: ModelConfig, mesh: Mesh, gcfg: GSPMDConfig,
+                   opt_cfg: AdamWConfig = AdamWConfig(), lr_schedule=None):
+    """``make_train_step`` jitted with the training state pinned to its FSDP
+    shardings on the way in and out, and donated: parameters and AdamW
+    moments are updated in place, never held twice."""
+    p_sh, o_sh = train_state_shardings(cfg, mesh, gcfg)
+    step = make_train_step(cfg, mesh, gcfg, opt_cfg, lr_schedule=lr_schedule)
+    return jax.jit(step, in_shardings=(p_sh, o_sh, None),
+                   out_shardings=(p_sh, o_sh, None), donate_argnums=(0, 1))
+
+
+def init_train_state(cfg: ModelConfig, mesh: Mesh, gcfg: GSPMDConfig, key):
+    """(params, AdamW state) created inside jit straight into their FSDP
+    shardings: no device ever holds an unsharded copy.  The values are
+    those of eager ``T.init_params`` / ``adamw_init`` with the same key."""
+    p_sh, o_sh = train_state_shardings(cfg, mesh, gcfg)
+
+    def init(k):
+        params = T.init_params(cfg, k, gcfg.param_dtype)
+        return params, adamw_init(params)
+
+    return jax.jit(init, out_shardings=(p_sh, o_sh))(key)
+
+
+def build_train_artifacts(cfg: ModelConfig, mesh: Mesh, gcfg: GSPMDConfig,
+                          batch_shapes, opt_cfg: AdamWConfig = AdamWConfig()):
+    """ShapeDtypeStruct stand-ins + jitted step ready to .lower() — no
+    device allocation (the dry-run path)."""
+    p_sh, o_sh = train_state_shardings(cfg, mesh, gcfg)
+    params_shape = jax.eval_shape(
+        lambda k: T.init_params(cfg, k, gcfg.param_dtype), jax.random.PRNGKey(0))
+    opt_shape = jax.eval_shape(adamw_init, params_shape)
+    b_sh = train_batch_shardings(batch_shapes, mesh, gcfg)
+    stand_in = lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                                  sharding=sh)
+    args = tuple(jax.tree.map(stand_in, shapes, sh) for shapes, sh in (
+        (params_shape, p_sh), (opt_shape, o_sh), (batch_shapes, b_sh)))
+    return jit_train_step(cfg, mesh, gcfg, opt_cfg), args
 
 
 # ===========================================================================

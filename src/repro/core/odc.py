@@ -37,7 +37,6 @@ from typing import Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.balance.cost import DeviceProfile
 from repro.obs import metrics as obs_metrics
 
@@ -52,7 +51,7 @@ def axis_size(axis_name: AxisNames):
     ax = _axis_tuple(axis_name)
     n = 1
     for a in ax:
-        n *= compat.axis_size(a)
+        n *= jax.lax.axis_size(a)
     return n
 
 
@@ -61,7 +60,7 @@ def axis_index(axis_name: AxisNames):
     ax = _axis_tuple(axis_name)
     idx = jax.lax.axis_index(ax[0])
     for a in ax[1:]:
-        idx = idx * compat.axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 
@@ -83,13 +82,13 @@ def _ppermute_next(x, axis_name: AxisNames,
     ax = _axis_tuple(axis_name)
     if len(ax) == 1:
         return jax.lax.ppermute(x, ax[0],
-                                _ring_perm(compat.axis_size(ax[0]), order))
+                                _ring_perm(jax.lax.axis_size(ax[0]), order))
     # multi-axis linearized ring: permute within the minor axis; the wrap
     # element moves one step along the major axis. Implemented as a minor-axis
     # ring followed by a conditional major-axis shift of the wrap position.
     # For simplicity and identical semantics we use the flat ppermute over the
     # combined axes, which JAX supports by passing the axis tuple.
-    sizes = [compat.axis_size(a) for a in ax]
+    sizes = [jax.lax.axis_size(a) for a in ax]
     n = 1
     for s in sizes:
         n *= s

@@ -95,8 +95,7 @@ class FSDPTrainer:
                                 *([None] * (x.ndim - 2))),
                     batch,
                 )
-            from repro import compat
-            sharded_grad = compat.shard_map(
+            sharded_grad = jax.shard_map(
                 grad_fn,
                 mesh=mesh,
                 in_specs=(sspecs, bspecs),

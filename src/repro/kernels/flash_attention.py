@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 NEG_INF = -2.0e38
 
 
@@ -115,7 +117,7 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=0,
                            logit_softcap=0.0, q_positions=None,
                            kv_positions=None, q_segment_ids=None,
                            kv_segment_ids=None, blk_q=128, blk_k=128,
-                           scale=None, interpret=True):
+                           scale=None, interpret=None):
     """q: (B, S, H, hd); k, v: (B, T, KH, hd) with H % KH == 0.
 
     Returns (B, S, H, hd).  S/T are padded to block multiples internally.
@@ -187,7 +189,7 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=0,
             pltpu.VMEM((blk_q,), jnp.float32),
             pltpu.VMEM((blk_q, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q_positions, kv_positions, q_segment_ids, kv_segment_ids, qh, kh, vh)
 
     out = out.reshape(B, H, Sp, hd)[:, :, :S]
@@ -298,7 +300,7 @@ def flash_attention_diff(q, k, v, *, causal=True, window=0,
                          logit_softcap=0.0, q_positions=None,
                          kv_positions=None, q_segment_ids=None,
                          kv_segment_ids=None, blk_q=128, blk_k=128,
-                         scale=None, interpret=True):
+                         scale=None, interpret=None):
     """Differentiable ``flash_attention_pallas``: the raw ``pallas_call``
     has no AD rule, so this wraps it in a custom VJP whose backward is
     :func:`flash_attention_bwd_ref`.  Forward is bitwise the kernel."""
@@ -315,7 +317,7 @@ def flash_attention_diff(q, k, v, *, causal=True, window=0,
     if kv_segment_ids is None:
         kv_segment_ids = jnp.zeros((B, T), jnp.int32)
     static = (bool(causal), int(window), float(logit_softcap), float(scale),
-              int(blk_q), int(blk_k), bool(interpret))
+              int(blk_q), int(blk_k), interpret_mode(interpret))
     return _flash_diff(static, q, k, v, q_positions, kv_positions,
                        q_segment_ids, kv_segment_ids)
 
@@ -341,7 +343,7 @@ def flash_attention_state(q, k, v, carry=None, *, causal=True, window=0,
                           logit_softcap=0.0, q_positions=None,
                           kv_positions=None, q_segment_ids=None,
                           kv_segment_ids=None, blk_q=128, blk_k=128,
-                          scale=None, interpret=True):
+                          scale=None, interpret=None):
     """One online-softmax sweep of q over a kv *chunk*, carrying state.
 
     q: (B, S, H, hd); k, v: (B, T, KH, hd) — T is the chunk length, not
@@ -438,7 +440,7 @@ def flash_attention_state(q, k, v, carry=None, *, causal=True, window=0,
             pltpu.VMEM((blk_q,), jnp.float32),
             pltpu.VMEM((blk_q, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q_positions, kv_positions, q_segment_ids, kv_segment_ids,
       qh, kh, vh, mh, lh, acch)
 

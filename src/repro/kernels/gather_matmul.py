@@ -21,18 +21,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
+from repro.kernels import remote_interpret
 
 
 def _gather_matmul_kernel(x_ref, w_ref, out_ref, wbuf_ref, acc_ref,
-                          send_sem, recv_sem, credit_sem, copy_sem, *,
-                          num, axis_name, with_credits):
+                          send_sem, recv_sem, credit_sem, *, num, axis_name):
     me = jax.lax.axis_index(axis_name)
-    dev_right, dev_type = compat.remote_device_id(jax.lax.rem(me + 1, num))
-    left = jax.lax.rem(me - 1 + num, num)
+    mesh_id = pltpu.DeviceIdType.MESH
+    right = (jax.lax.rem(me + 1, num),)
+    left = (jax.lax.rem(me - 1 + num, num),)
     c = w_ref.shape[0]  # rows per shard
 
-    compat.sync_copy(w_ref, wbuf_ref.at[0], copy_sem)
+    pltpu.sync_copy(w_ref, wbuf_ref.at[0])
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # Credit-based flow control: the two staging slots give two hops of
@@ -44,18 +44,17 @@ def _gather_matmul_kernel(x_ref, w_ref, out_ref, wbuf_ref, acc_ref,
         slot = jax.lax.rem(i, 2)
         nxt = jax.lax.rem(i + 1, 2)
 
-        if with_credits:
-            @pl.when(i >= 2)
-            def _backpressure():
-                pltpu.semaphore_wait(credit_sem, 1)
+        @pl.when(i >= 2)
+        def _backpressure():
+            pltpu.semaphore_wait(credit_sem, 1)
 
         rdma = pltpu.make_async_remote_copy(
             src_ref=wbuf_ref.at[slot],
             dst_ref=wbuf_ref.at[nxt],
             send_sem=send_sem.at[slot],
             recv_sem=recv_sem.at[nxt],
-            device_id=dev_right,
-            device_id_type=dev_type,
+            device_id=right,
+            device_id_type=mesh_id,
         )
         rdma.start()
         # matmul on the resident shard while the DMA is in flight
@@ -65,11 +64,10 @@ def _gather_matmul_kernel(x_ref, w_ref, out_ref, wbuf_ref, acc_ref,
                                 preferred_element_type=jnp.float32)
         rdma.wait()
 
-        if with_credits:
-            @pl.when(i <= num - 3)
-            def _credit():  # slot `slot` is free for the left neighbor now
-                pltpu.semaphore_signal(credit_sem, 1, device_id=left,
-                                       device_id_type=dev_type)
+        @pl.when(i <= num - 3)
+        def _credit():  # slot `slot` is free for the left neighbor now
+            pltpu.semaphore_signal(credit_sem, 1, device_id=left,
+                                   device_id_type=mesh_id)
 
         return 0
 
@@ -80,15 +78,14 @@ def _gather_matmul_kernel(x_ref, w_ref, out_ref, wbuf_ref, acc_ref,
 
 
 def gather_matmul_pallas(x, w_shard, *, axis_name: str,
-                         interpret: bool = True):
+                         interpret=None):
     """x: (m, k) replicated; w_shard: (k/n, f) local rows.  Returns
     (m, f) = x @ W_full, identical on every device along ``axis_name``."""
     m, k = x.shape
     c, f = w_shard.shape
     kernel = functools.partial(
-        _gather_matmul_kernel, num=compat.axis_size(axis_name),
-        axis_name=axis_name,
-        with_credits=compat.supports_remote_semaphore_signal(interpret))
+        _gather_matmul_kernel, num=jax.lax.axis_size(axis_name),
+        axis_name=axis_name)
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((m, f), x.dtype),
@@ -103,8 +100,6 @@ def gather_matmul_pallas(x, w_shard, *, axis_name: str,
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.REGULAR,
-            pltpu.SemaphoreType.DMA,
         ],
-        compiler_params=compat.tpu_compiler_params(collective_id=2),
-        interpret=compat.interpret_params(interpret),
+        interpret=remote_interpret(interpret),
     )(x, w_shard)

@@ -10,173 +10,100 @@ pairwise producer/consumer handoff, which is exactly the non-intrusive
 property §3.2 needs (the peer's compute core is never interrupted; the
 DMA engines move the bytes).
 
-Layout: shards live in HBM (``pl.ANY``); a two-slot VMEM staging buffer
-double-buffers the in-flight hop.
+Layout: shards and the gathered output live in HBM (``pl.ANY``) and every
+hop is an HBM-to-HBM DMA straight into the neighbor's output slot for that
+shard.  Nothing is staged in VMEM, so a shard of any size fits, and since
+each output slot is written exactly once no slot is ever reused — the ring
+needs no credits.  A sender never waits for its receiver, so a fast left
+neighbor may have several pushes in flight into me; each output slot
+therefore has its own receive semaphore, and a shard is forwarded only
+after the wait on ITS semaphore (a shared one could be satisfied by a later
+hop that landed first).
 
 Two entry points:
 
   odc_gather_pallas         one layer's shard set -> full layer
   odc_gather_layers_pallas  a stacked (L, c, ...) shard set -> (L, n, c, ...)
-                            with the ring hops of consecutive layers chained
-                            through the SAME two staging slots (a single
-                            global hop counter), so layer l+1's first hop
-                            can be in flight while layer l's last shards are
-                            still being committed — the cross-layer
-                            double-buffered prefetch that backs
-                            ``schedule='overlap'``.  A two-slot *inject*
-                            buffer stages each layer's own shard so the
-                            layer-boundary re-stage never races the left
-                            neighbor's in-flight write into the ring slots.
-
-Credit-based backpressure (a sender holds until the receiver has consumed
-the staging slot it is about to overwrite) is only emitted on real TPU:
-interpret mode executes hops synchronously and its discharge rules do not
-implement remote semaphore signals.
+                            with the rings of consecutive layers chained
+                            without a barrier between them, so layer l+1's
+                            first hop can be in flight while layer l's last
+                            shards are still arriving elsewhere on the ring
+                            — the cross-layer prefetch that backs
+                            ``schedule='overlap'``.
 """
 from __future__ import annotations
 
 import functools
 
 import jax
-import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
+from repro.kernels import remote_interpret
 
 
-def _gather_kernel(x_ref, out_ref, buf_ref, send_sem, recv_sem, credit_sem,
-                   copy_sem, *, num, axis_name, with_credits):
+def _forward(out_at, recv_at, src, nxt, right, send_sem):
+    """One hop: push shard ``src`` from my output into the right neighbor's
+    output slot for it, then wait until shard ``nxt`` (my left neighbor's
+    push) has landed in mine."""
+    rdma = pltpu.make_async_remote_copy(
+        src_ref=out_at(src), dst_ref=out_at(src),
+        send_sem=send_sem, recv_sem=recv_at(src),
+        device_id=(right,), device_id_type=pltpu.DeviceIdType.MESH)
+    rdma.start()
+    rdma.wait_send()
+    # pairwise sync with the two ring neighbors only
+    pltpu.make_async_copy(out_at(nxt), out_at(nxt), recv_at(nxt)).wait()
+
+
+def _gather_kernel(x_ref, out_ref, send_sem, recv_sem, *, num, axis_name):
     me = jax.lax.axis_index(axis_name)
-    dev_right, dev_type = compat.remote_device_id(jax.lax.rem(me + 1, num))
-    left = jax.lax.rem(me - 1 + num, num)
+    right = jax.lax.rem(me + 1, num)
+    pltpu.sync_copy(x_ref, out_ref.at[me])  # my own shard, HBM -> HBM
 
-    # my own shard: HBM -> HBM copy into my slot of the output
-    compat.sync_copy(x_ref, out_ref.at[me], copy_sem)
-    # stage my shard for the first hop
-    compat.sync_copy(x_ref, buf_ref.at[0], copy_sem)
-
-    # Two staging slots give two hops of slack; beyond that a sender must
-    # hold until the receiver has consumed the slot it is about to
-    # overwrite (credit signaled back after the receiver's copy-out).
     def hop(i, _):
-        slot = jax.lax.rem(i, 2)
-        nxt = jax.lax.rem(i + 1, 2)
-
-        if with_credits:
-            @pl.when(i >= 2)
-            def _backpressure():
-                pltpu.semaphore_wait(credit_sem, 1)
-
-        rdma = pltpu.make_async_remote_copy(
-            src_ref=buf_ref.at[slot],
-            dst_ref=buf_ref.at[nxt],
-            send_sem=send_sem.at[slot],
-            recv_sem=recv_sem.at[nxt],
-            device_id=dev_right,
-            device_id_type=dev_type,
-        )
-        rdma.start()
-        rdma.wait()  # pairwise sync with the two ring neighbors only
-        src = jax.lax.rem(me - i - 1 + num, num)  # who produced this shard
-        compat.sync_copy(buf_ref.at[nxt], out_ref.at[src], copy_sem)
-
-        if with_credits:
-            @pl.when(i <= num - 4)
-            def _credit():  # buf[slot] is reusable by the left neighbor
-                pltpu.semaphore_signal(credit_sem, 1, device_id=left,
-                                       device_id_type=dev_type)
-
+        # hop 0 forwards my shard; hop i forwards what arrived on hop i-1
+        src = jax.lax.rem(me - i + num, num)
+        nxt = jax.lax.rem(me - i - 1 + num, num)
+        _forward(lambda s: out_ref.at[s], lambda s: recv_sem.at[s], src, nxt,
+                 right, send_sem)
         return 0
 
     jax.lax.fori_loop(0, num - 1, hop, 0)
 
 
-def odc_gather_pallas(x, *, axis_name: str, interpret: bool = True):
+def odc_gather_pallas(x, *, axis_name: str, interpret=None):
     """x: local shard (c, ...) inside shard_map -> (n, c, ...) stacked
     shards (caller reshapes to the tiled gather layout)."""
-    n = compat.axis_size(axis_name)
-    out_shape = jax.ShapeDtypeStruct((n,) + x.shape, x.dtype)
-    kernel = functools.partial(
-        _gather_kernel, num=n, axis_name=axis_name,
-        with_credits=compat.supports_remote_semaphore_signal(interpret))
+    n = jax.lax.axis_size(axis_name)
+    kernel = functools.partial(_gather_kernel, num=n, axis_name=axis_name)
     return pl.pallas_call(
         kernel,
-        out_shape=out_shape,
+        out_shape=jax.ShapeDtypeStruct((n,) + x.shape, x.dtype),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        scratch_shapes=[
-            pltpu.VMEM((2,) + x.shape, x.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.REGULAR,
-            pltpu.SemaphoreType.DMA,
-        ],
-        compiler_params=compat.tpu_compiler_params(collective_id=0),
-        interpret=compat.interpret_params(interpret),
+        scratch_shapes=[pltpu.SemaphoreType.DMA,
+                        pltpu.SemaphoreType.DMA((n,))],
+        interpret=remote_interpret(interpret),
     )(x)
 
 
-def _gather_layers_kernel(x_ref, out_ref, buf_ref, inj_ref, send_sem,
-                          recv_sem, credit_sem, copy_sem, *, num, layers,
-                          axis_name, with_credits):
-    """Chained rings over a stacked (L, c, ...) shard set.
-
-    One GLOBAL hop counter h = l*(num-1) + i indexes the two staging slots,
-    so consecutive layers reuse them back-to-back without an inter-layer
-    barrier — the two-slot double buffer extended across layers.  Each
-    layer's own shard is staged in a separate two-slot inject buffer: the
-    ring slots are receive targets for the (possibly two-hops-ahead) left
-    neighbor, so re-staging into them at a layer boundary would race.
-    """
+def _gather_layers_kernel(x_ref, out_ref, send_sem, recv_sem, *, num,
+                          layers, axis_name):
+    """Chained rings over a stacked (L, c, ...) shard set: layer l+1 starts
+    as soon as this device has finished layer l's hops.  The left neighbor
+    may run layers ahead, so receive semaphores are per (layer, slot)."""
     me = jax.lax.axis_index(axis_name)
-    dev_right, dev_type = compat.remote_device_id(jax.lax.rem(me + 1, num))
-    left = jax.lax.rem(me - 1 + num, num)
-    hops_total = layers * (num - 1)
+    right = jax.lax.rem(me + 1, num)
 
     def layer(l, _):
-        compat.sync_copy(x_ref.at[l], out_ref.at[l, me], copy_sem)
-        compat.sync_copy(x_ref.at[l], inj_ref.at[jax.lax.rem(l, 2)], copy_sem)
+        pltpu.sync_copy(x_ref.at[l], out_ref.at[l, me])
 
         def hop(i, _):
-            h = l * (num - 1) + i
-            slot = jax.lax.rem(h, 2)
-            nxt = jax.lax.rem(h + 1, 2)
-
-            if with_credits:
-                @pl.when(h >= 2)
-                def _backpressure():
-                    pltpu.semaphore_wait(credit_sem, 1)
-
-            def _send(src_ref):
-                rdma = pltpu.make_async_remote_copy(
-                    src_ref=src_ref,
-                    dst_ref=buf_ref.at[nxt],
-                    send_sem=send_sem.at[slot],
-                    recv_sem=recv_sem.at[nxt],
-                    device_id=dev_right,
-                    device_id_type=dev_type,
-                )
-                rdma.start()
-                rdma.wait()
-
-            @pl.when(i == 0)
-            def _first():  # layer l's own shard enters the ring
-                _send(inj_ref.at[jax.lax.rem(l, 2)])
-
-            @pl.when(i > 0)
-            def _forward():  # forward what arrived on the previous hop
-                _send(buf_ref.at[slot])
-
-            src = jax.lax.rem(me - i - 1 + num, num)
-            compat.sync_copy(buf_ref.at[nxt], out_ref.at[l, src], copy_sem)
-
-            if with_credits:
-                @pl.when(h <= hops_total - 3)
-                def _credit():
-                    pltpu.semaphore_signal(credit_sem, 1, device_id=left,
-                                           device_id_type=dev_type)
-
+            src = jax.lax.rem(me - i + num, num)
+            nxt = jax.lax.rem(me - i - 1 + num, num)
+            _forward(lambda s: out_ref.at[l, s], lambda s: recv_sem.at[l, s],
+                     src, nxt, right, send_sem)
             return 0
 
         jax.lax.fori_loop(0, num - 1, hop, 0)
@@ -185,30 +112,20 @@ def _gather_layers_kernel(x_ref, out_ref, buf_ref, inj_ref, send_sem,
     jax.lax.fori_loop(0, layers, layer, 0)
 
 
-def odc_gather_layers_pallas(x, *, axis_name: str, interpret: bool = True):
+def odc_gather_layers_pallas(x, *, axis_name: str, interpret=None):
     """x: stacked local shards (L, c, ...) inside shard_map ->
     (L, n, c, ...): every layer's full shard set, gathered by L chained
-    rings sharing one double-buffered staging pair (no per-layer barrier)."""
-    n = compat.axis_size(axis_name)
+    rings (no per-layer barrier)."""
+    n = jax.lax.axis_size(axis_name)
     L = x.shape[0]
-    chunk = x.shape[1:]
-    out_shape = jax.ShapeDtypeStruct((L, n) + chunk, x.dtype)
-    kernel = functools.partial(
-        _gather_layers_kernel, num=n, layers=L, axis_name=axis_name,
-        with_credits=compat.supports_remote_semaphore_signal(interpret))
+    kernel = functools.partial(_gather_layers_kernel, num=n, layers=L,
+                               axis_name=axis_name)
     return pl.pallas_call(
         kernel,
-        out_shape=out_shape,
+        out_shape=jax.ShapeDtypeStruct((L, n) + x.shape[1:], x.dtype),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        scratch_shapes=[
-            pltpu.VMEM((2,) + chunk, x.dtype),
-            pltpu.VMEM((2,) + chunk, x.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.REGULAR,
-            pltpu.SemaphoreType.DMA,
-        ],
-        compiler_params=compat.tpu_compiler_params(collective_id=0),
-        interpret=compat.interpret_params(interpret),
+        scratch_shapes=[pltpu.SemaphoreType.DMA,
+                        pltpu.SemaphoreType.DMA((L, n))],
+        interpret=remote_interpret(interpret),
     )(x)

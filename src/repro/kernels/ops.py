@@ -1,7 +1,8 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On this container everything runs with ``interpret=True`` (CPU); on a real
-TPU pass ``interpret=False`` (the default flips on TPU backends).
+``interpret=None`` (the default) compiles the kernels on a TPU and
+interprets them anywhere else; ``repro.kernels.interpret_mode`` decides,
+for every kernel.
 """
 from __future__ import annotations
 
@@ -26,14 +27,9 @@ from repro.kernels.quant import (
 from repro.kernels.ssd_scan import ssd_scan_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def odc_gather(x_shard, axis_name: str, *, interpret=None):
     """Inside shard_map: (c, ...) local shard -> (n*c, ...) full tensor,
     via one-sided remote-DMA ring hops (no fused collective)."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
     stacked = odc_gather_pallas(x_shard, axis_name=axis_name,
                                 interpret=interpret)
     n = stacked.shape[0]
@@ -43,9 +39,7 @@ def odc_gather(x_shard, axis_name: str, *, interpret=None):
 def odc_scatter_accumulate(y, axis_name: str, *, interpret=None):
     """Inside shard_map: (n*c, ...) local contribution -> (c, ...) owned,
     fully-accumulated chunk."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
-    from repro import compat
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     c = y.shape[0] // n
     stacked = y.reshape((n, c) + y.shape[1:])
     return odc_scatter_accumulate_pallas(stacked, axis_name=axis_name,
@@ -54,9 +48,8 @@ def odc_scatter_accumulate(y, axis_name: str, *, interpret=None):
 
 def odc_gather_layers(x_stacked, axis_name: str, *, interpret=None):
     """Inside shard_map: (L, c, ...) stacked local shards -> (L, n*c, ...)
-    per-layer full tensors.  The L ring chains share one double-buffered
-    staging pair (cross-layer prefetch, schedule='overlap')."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    per-layer full tensors.  The L ring chains run with no inter-layer
+    barrier (cross-layer prefetch, schedule='overlap')."""
     stacked = odc_gather_layers_pallas(x_stacked, axis_name=axis_name,
                                        interpret=interpret)
     L, n, c = stacked.shape[0], stacked.shape[1], stacked.shape[2]
@@ -67,10 +60,8 @@ def odc_scatter_accumulate_layers(y_stacked, axis_name: str, *,
                                   interpret=None):
     """Inside shard_map: (L, n*c, ...) stacked contributions -> (L, c, ...)
     owned, fully-accumulated chunks, with the L scatter rings chained
-    through one double-buffered staging pair."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
-    from repro import compat
-    n = compat.axis_size(axis_name)
+    through one pair of receive slots."""
+    n = jax.lax.axis_size(axis_name)
     L, full = y_stacked.shape[0], y_stacked.shape[1]
     c = full // n
     stacked = y_stacked.reshape((L, n, c) + y_stacked.shape[2:])
@@ -91,14 +82,12 @@ def quantize_int8(x, *, interpret=None):
     """Chunked-int8 encode (Pallas codec kernel): any-shape tensor ->
     ((n_chunks, chunk) int8 values, (n_chunks, 1) f32 scales) — the wire
     format of ``repro.core.odc.quantize_chunked`` (its jnp oracle)."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
     from repro.core.odc import INT8_CHUNK
     return quantize_pallas(_chunk_blocks(x, INT8_CHUNK), interpret=interpret)
 
 
 def dequantize_int8(q, scales, shape, dtype=jnp.float32, *, interpret=None):
     """Invert :func:`quantize_int8` back to a tensor of ``shape``."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
     flat = dequantize_pallas(q, scales, interpret=interpret).reshape(-1)
     size = 1
     for s in shape:
@@ -111,9 +100,7 @@ def odc_gather_q8(x_shard, axis_name: str, *, interpret=None):
     with the ring payload chunked-int8 compressed — quantized ONCE at each
     shard's origin (error does not compound with ring distance); the local
     shard lands exactly."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
-    from repro import compat
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     me = jax.lax.axis_index(axis_name)
     q, scales = quantize_int8(x_shard, interpret=interpret)
     qs, ss = odc_gather_q8_pallas(q, scales, axis_name=axis_name,
@@ -130,10 +117,8 @@ def odc_scatter_accumulate_q8(y, axis_name: str, *, interpret=None):
     """Inside shard_map: (n*c, ...) local contribution -> (c, ...) owned,
     fully-accumulated chunk, with every hop's outgoing partial sum
     requantized to the chunked-int8 wire format."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
-    from repro import compat
     from repro.core.odc import INT8_CHUNK
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     c = y.shape[0] // n
     flat = y.reshape(n, -1).astype(jnp.float32)
     pad = (-flat.shape[1]) % INT8_CHUNK
@@ -149,7 +134,6 @@ def odc_scatter_accumulate_q8(y, axis_name: str, *, interpret=None):
 def gather_matmul(x, w_shard, axis_name: str, *, interpret=None):
     """Inside shard_map: x (m, k) replicated, w_shard (k/n, f) local ->
     (m, f) = x @ W_full, with the ring DMA hidden under the matmuls."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
     return gather_matmul_pallas(x, w_shard, axis_name=axis_name,
                                 interpret=interpret)
 
@@ -160,7 +144,6 @@ def flash_attention(q, k, v, *, causal=True, window=0, logit_softcap=0.0,
                     q_positions=None, kv_positions=None, q_segment_ids=None,
                     kv_segment_ids=None, blk_q=128, blk_k=128,
                     interpret=None):
-    interpret = (not _on_tpu()) if interpret is None else interpret
     return flash_attention_pallas(
         q, k, v, causal=causal, window=window, logit_softcap=logit_softcap,
         q_positions=q_positions, kv_positions=kv_positions,
@@ -170,6 +153,5 @@ def flash_attention(q, k, v, *, causal=True, window=0, logit_softcap=0.0,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int, interpret=None):
-    interpret = (not _on_tpu()) if interpret is None else interpret
     return ssd_scan_pallas(x, dt, A, Bm, Cm, chunk=chunk,
                            interpret=interpret)

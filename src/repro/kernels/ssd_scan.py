@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_out_ref,
                 state_ref, *, num_chunks):
@@ -67,7 +69,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_out_ref,
         state_out_ref[0, 0] = state_ref[...].astype(state_out_ref.dtype)
 
 
-def ssd_scan_pallas(x, dt, A, Bm, Cm, *, chunk: int, interpret: bool = True):
+def ssd_scan_pallas(x, dt, A, Bm, Cm, *, chunk: int, interpret=None):
     """Same contract as ``repro.models.ssm.ssd_chunked`` (without initial
     state): x (b, s, h, p); dt (b, s, h); A (h,); Bm/Cm (b, s, g, n) with
     h % g == 0.  Returns (y (b, s, h, p), final_state (b, h, p, n))."""
@@ -101,6 +103,6 @@ def ssd_scan_pallas(x, dt, A, Bm, Cm, *, chunk: int, interpret: bool = True):
             jax.ShapeDtypeStruct((b, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, dt, A, Bm, Cm)
     return y, state

@@ -125,12 +125,16 @@ def run_combo(arch: str, shape_name: str, *, multi_pod: bool = False,
             f.write(text)
     devices_per_pod = (chips // mesh.shape["pod"]) if multi_pod else 0
     cost = hlo_mod.analyze_hlo_text(text, devices_per_pod=devices_per_pod)
+    # the host devices stand in for the production chips: price them as such
+    kind = hlo_mod.TARGET_DEVICE_KIND
     roof = hlo_mod.roofline_terms(
-        cost, chips=chips, model_flops=model_flops_estimate(cfg, shape))
+        cost, chips=chips, device_kind=kind,
+        model_flops=model_flops_estimate(cfg, shape))
     if multi_pod:
         # DCN term: cross-pod bytes at data-center-network bandwidth
         roof["inter_pod_bytes_per_device"] = cost.inter_pod_bytes
-        roof["dcn_s"] = cost.inter_pod_bytes / hlo_mod.DCN_BW
+        roof["dcn_s"] = (cost.inter_pod_bytes
+                         / hlo_mod.peak_rates(kind)["dcn_bw"])
 
     return {
         "arch": arch,

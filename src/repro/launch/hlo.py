@@ -427,20 +427,39 @@ def analyze_hlo_text(text: str, *, devices_per_pod: int = 0) -> Cost:
 
 
 # ===========================================================================
-# roofline terms (TPU v5e target constants)
+# roofline terms: per-chip peaks keyed by jax ``device_kind``
 # ===========================================================================
-PEAK_FLOPS = 197e12  # bf16 / chip
-HBM_BW = 819e9       # bytes/s / chip
-ICI_BW = 50e9        # bytes/s / link (per direction)
-DCN_BW = 6.25e9      # bytes/s / chip across pods (~50 Gb/s effective)
+# Source: Google Cloud documentation, "TPU v5e" (system architecture):
+# 197 TFLOP/s bf16, 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of inter-chip
+# interconnect over 4 links = 50 GB/s per link and direction.  The DCN
+# figure is this repo's modeling assumption (~50 Gbit/s effective per chip
+# across pods), not a published peak.
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9,
+                    "dcn_bw": 6.25e9},
+}
+# the chip the dry-run's production meshes model (repro.launch.mesh)
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 
-def roofline_terms(cost: Cost, *, chips: int, model_flops: float = 0.0):
+def peak_rates(device_kind: str) -> dict:
+    """Published peaks of one chip of ``device_kind``; a kind without a
+    row in ``PEAKS`` is an error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peak rates for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
+
+
+def roofline_terms(cost: Cost, *, chips: int, device_kind: str,
+                   model_flops: float = 0.0):
     """cost is PER DEVICE; returns the three roofline terms in seconds plus
     bookkeeping.  model_flops is the global 6·N·D estimate."""
-    compute_t = cost.flops / PEAK_FLOPS
-    memory_t = cost.bytes / HBM_BW
-    coll_t = cost.total_coll_bytes / ICI_BW
+    peak = peak_rates(device_kind)
+    compute_t = cost.flops / peak["flops"]
+    memory_t = cost.bytes / peak["hbm_bw"]
+    coll_t = cost.total_coll_bytes / peak["ici_bw"]
     terms = {"compute_s": compute_t, "memory_s": memory_t,
              "collective_s": coll_t}
     dom = max(terms, key=terms.get)
@@ -453,6 +472,7 @@ def roofline_terms(cost: Cost, *, chips: int, model_flops: float = 0.0):
         "collective_bytes_by_type": dict(cost.coll_bytes),
         "collective_count_by_type": dict(cost.coll_count),
         "chips": chips,
+        "device_kind": device_kind,
     }
     if model_flops:
         hlo_global = cost.flops * chips

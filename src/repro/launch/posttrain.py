@@ -241,4 +241,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     raise SystemExit(main())

@@ -10,14 +10,23 @@ microbatches — under the ODC schedule the loop body has no collectives,
 so on real hardware the pad cost collapses to the minibatch barrier
 (paper Fig. 2); the timing consequences are modeled in ``repro.sim``.
 
+The parameters and AdamW state are created inside jit straight into their
+FSDP shardings and the step donates them, so the state is never held
+twice and never lands unsharded on one device.
+
 Example (CPU, reduced config):
-  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+  XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
   PYTHONPATH=src python -m repro.launch.train --arch qwen-1.5b --reduced \
       --steps 20 --strategy lb_mini --schedule minibatch --comm odc
+
+Example (one TPU chip, published widths, depth cut to 8 layers):
+  PYTHONPATH=src python -m repro.launch.train --arch qwen-1.5b --layers 8 \
+      --steps 3
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
@@ -28,7 +37,13 @@ from repro.balance.cost import CostModel
 from repro.checkpoint import latest_step, load_checkpoint, save_checkpoint
 from repro.configs import get_config, get_reduced
 from repro.core import backend as backends
-from repro.core.gspmd import GSPMDConfig, ShardingRules, make_train_step
+from repro.core.gspmd import (
+    GSPMDConfig,
+    ShardingRules,
+    init_train_state,
+    jit_train_step,
+    train_batch_shardings,
+)
 from repro.data.loader import SyntheticSFTLoader
 from repro.data.packing import build_minibatch  # noqa: F401 (re-export:
 #   the plan->batch assembly now lives in repro.data.packing, shared with
@@ -39,10 +54,9 @@ from repro.launch.mesh import (
     make_host_mesh,
     make_pipe_mesh,
 )
-from repro.models import transformer as T
 from repro.obs import log as obs_log
 from repro.obs import metrics as obs_metrics
-from repro.optim import AdamWConfig, adamw_init
+from repro.optim import AdamWConfig
 from repro.sim.trace import TraceRecorder, maybe_span
 
 
@@ -51,6 +65,9 @@ def main(argv=None):
     ap.add_argument("--arch", default="qwen-1.5b")
     ap.add_argument("--reduced", action="store_true",
                     help="smoke-scale variant of the same family")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the config's depth to N layers; every width "
+                         "stays as published (0 = the config's own depth)")
     ap.add_argument("--dataset", default="longalign",
                     choices=("longalign", "swesmith", "aime"))
     ap.add_argument("--strategy", default="lb_mini",
@@ -157,6 +174,11 @@ def main(argv=None):
                  f"{tuned['winner']} (CLI flags override)")
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
+    if args.layers:
+        full_depth = cfg.num_layers
+        cfg = dataclasses.replace(cfg, num_layers=args.layers)
+        out.info(f"depth cut: {args.layers} of {full_depth} layers, widths "
+                 "unchanged")
     comm = backends.get_backend(args.comm)  # resolve aliases up front
     if comm.name == "hier":
         # two-tier FSDP: params sharded node-major over (node, device)
@@ -179,9 +201,11 @@ def main(argv=None):
         mesh = make_host_mesh(data=args.data_axis, model=args.model_axis)
         rules = ShardingRules()
         world = mesh.shape["data"]
-    out.info(f"{cfg.name} ({cfg.family}) on mesh {dict(mesh.shape)} "
-             f"strategy={args.strategy} schedule={args.schedule} "
-             f"comm={comm.name}")
+    dev = jax.devices()[0]
+    out.info(f"{cfg.name} ({cfg.family}) on {dev.platform} "
+             f"{dev.device_kind} x{jax.device_count()}, mesh "
+             f"{dict(mesh.shape)} strategy={args.strategy} "
+             f"schedule={args.schedule} comm={comm.name}")
 
     profile = None
     if args.device_profile != "none":
@@ -205,12 +229,10 @@ def main(argv=None):
         lr_schedule = (lambda s: cosine_schedule(
             s, args.steps, args.warmup_steps)) if args.cosine else \
             (lambda s: jnp.minimum(1.0, (s + 1) / max(1, args.warmup_steps)))
-    step_fn = jax.jit(make_train_step(cfg, mesh, gcfg,
-                                      AdamWConfig(lr=args.lr),
-                                      lr_schedule=lr_schedule))
-
-    params = T.init_params(cfg, jax.random.PRNGKey(args.seed))
-    opt_state = adamw_init(params)
+    step_fn = jit_train_step(cfg, mesh, gcfg, AdamWConfig(lr=args.lr),
+                             lr_schedule=lr_schedule)
+    params, opt_state = init_train_state(cfg, mesh, gcfg,
+                                         jax.random.PRNGKey(args.seed))
 
     start_step = 0
     if args.resume:
@@ -218,8 +240,10 @@ def main(argv=None):
             raise SystemExit("--resume needs --ckpt-dir")
         last = latest_step(args.ckpt_dir)
         if last is not None:
-            state = load_checkpoint(args.ckpt_dir, last,
-                                    {"params": params, "opt": opt_state})
+            fresh = {"params": params, "opt": opt_state}
+            state = load_checkpoint(
+                args.ckpt_dir, last, fresh,
+                shardings=jax.tree.map(lambda x: x.sharding, fresh))
             params, opt_state = state["params"], state["opt"]
             start_step = last
             out.info(f"resumed from {args.ckpt_dir} at step {last}")
@@ -278,6 +302,8 @@ def main(argv=None):
                                         step_data["sample_tokens"],
                                         args.max_tokens,
                                         extras=extras_for(i))
+                batch = jax.device_put(
+                    batch, train_batch_shardings(batch, mesh, gcfg))
             t0 = time.time()
             with maybe_span(rec, "trainer", "compute", f"train step {i}"):
                 # program scope: a retrace (new batch shapes) REPLACES the
@@ -329,4 +355,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     raise SystemExit(main())

@@ -105,8 +105,6 @@ def use_pallas_flash_attention(*, interpret=None, blk_q=128, blk_k=128):
                 kv_positions=kv_positions, q_segment_ids=q_segment_ids,
                 kv_segment_ids=kv_segment_ids,
                 block_kv=block_kv or k.shape[1], scale=scale)
-        interp = (jax.default_backend() != "tpu") if interpret is None \
-            else interpret
         # the custom-VJP wrapper: pallas forward, closed-form jnp backward
         # (raw pallas_call has no AD rule)
         return flash_attention_diff(
@@ -115,7 +113,7 @@ def use_pallas_flash_attention(*, interpret=None, blk_q=128, blk_k=128):
             q_positions=q_positions, kv_positions=kv_positions,
             q_segment_ids=q_segment_ids, kv_segment_ids=kv_segment_ids,
             blk_q=blk_q, blk_k=min(blk_k, block_kv) if block_kv else blk_k,
-            scale=scale, interpret=interp)
+            scale=scale, interpret=interpret)
 
     return _AttnImplGuard(set_attention_impl(impl))
 
@@ -123,14 +121,21 @@ def use_pallas_flash_attention(*, interpret=None, blk_q=128, blk_k=128):
 # --------------------------------------------------------------------------
 # initialization helpers
 # --------------------------------------------------------------------------
+def _normal(key, shape):
+    """Standard normals, fenced so that a jitted (e.g. sharded) init cannot
+    fold the caller's scale into the sampler's own arithmetic: the values
+    are then bitwise those of an eager init."""
+    return jax.lax.optimization_barrier(jax.random.normal(key, shape))
+
+
 def dense_init(key, shape, dtype, scale: float = 1.0):
     fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
     std = scale / (fan_in ** 0.5)
-    return (jax.random.normal(key, shape) * std).astype(dtype)
+    return (_normal(key, shape) * std).astype(dtype)
 
 
 def embed_init(key, shape, dtype):
-    return (jax.random.normal(key, shape) * 0.02).astype(dtype)
+    return (_normal(key, shape) * 0.02).astype(dtype)
 
 
 # --------------------------------------------------------------------------

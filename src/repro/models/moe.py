@@ -189,8 +189,7 @@ def _moe_apply_ep(cfg, p, x, axis_name, cf):
     all_to_all'd back and combined."""
     B, S, d = x.shape
     E, k = cfg.num_experts, cfg.experts_per_token
-    from repro import compat
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     E_local = p["w_up"].shape[0]
     assert E_local * n == E, (E_local, n, E)
 

@@ -27,7 +27,6 @@ from typing import Any, List, Optional, Tuple
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import backend as B
 from repro.core.gspmd import (
     GSPMDConfig, _data_dims, _keep_axes, param_pspecs,
@@ -68,7 +67,7 @@ def make_weight_push(cfg: ModelConfig, mesh, gcfg: GSPMDConfig):
 
         return jax.tree.map(g, params_local, pspecs)
 
-    sharded = compat.shard_map(
+    sharded = jax.shard_map(
         push_local, mesh=mesh, in_specs=(manual_pspecs,),
         out_specs=out_specs, check_vma=False, axis_names=set(manual))
     return jax.jit(sharded)

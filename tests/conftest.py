@@ -5,11 +5,6 @@ in file order — so the 8-host-device flag the distributed tests need must
 be set before ANY module imports jax.  (This is deliberately 8, not the
 dry-run's 512: only `repro.launch.dryrun` builds the production mesh, in
 its own process.)
-
-The tests are written against the current ``jax.shard_map`` API; on older
-jax (0.4.x, where shard_map still lives in jax.experimental) the
-``repro.compat`` wrapper is aliased in so the same test code runs
-unchanged.
 """
 import os
 import sys
@@ -19,14 +14,7 @@ os.environ.setdefault("XLA_FLAGS",
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-import jax  # noqa: E402
 import pytest  # noqa: E402
-
-if not hasattr(jax, "shard_map"):
-    from repro import compat
-
-    jax.shard_map = compat.shard_map
-
 
 # ---------------------------------------------------------------------------
 # fault injection: seeded straggler profiles

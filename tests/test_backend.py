@@ -25,7 +25,6 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 from repro.balance import STRATEGIES
 from repro.balance.cost import DeviceProfile, make_straggler_profile
 from repro.configs import get_reduced
@@ -89,9 +88,9 @@ def test_sim_discipline_vocabulary():
 # primitive parity: registry backends run the exact pre-refactor ops
 # ===========================================================================
 def _shard_run(fn, mesh, in_specs, out_specs):
-    return compat.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_vma=False,
-                            axis_names=set(a for a in mesh.axis_names))
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False,
+                         axis_names=set(a for a in mesh.axis_names))
 
 
 def test_param_gather_matches_raw_primitives_bitwise():
@@ -176,9 +175,7 @@ def test_node_collapse():
 # engine parity: alias spellings are bit-identical; hier matches pure FSDP
 # ===========================================================================
 def _mesh():
-    if compat.supports_partial_auto():
-        return make_host_mesh(data=4, model=2)
-    return make_host_mesh(data=8, model=1)
+    return make_host_mesh(data=4, model=2)
 
 
 def _batch(cfg, M=2, Bm=8, S=32):

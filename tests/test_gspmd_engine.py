@@ -30,13 +30,9 @@ MODES = [("layer", "collective"), ("layer", "odc"),
 
 
 def _mesh():
-    # TP + FSDP when the installed XLA supports partially-manual SPMD;
-    # pure FSDP (the paper's setting) otherwise — the schedule/comm
-    # semantics under test live entirely on the data axis.
-    from repro import compat
-    if compat.supports_partial_auto():
-        return make_host_mesh(data=4, model=2)
-    return make_host_mesh(data=8, model=1)
+    # TP + FSDP: the schedule/comm semantics under test live entirely on
+    # the data axis; the model axis checks they compose with GSPMD TP
+    return make_host_mesh(data=4, model=2)
 
 
 def _batch(cfg, M=2, Bm=8, S=32):
@@ -171,11 +167,8 @@ def test_serve_artifacts_lower(arch):
 
 
 def test_multipod_flat_and_hybrid_lower():
-    from repro import compat
     cfg = get_reduced("gemma2-9b")
-    mesh = (make_host_mesh(data=2, model=2, pod=2)
-            if compat.supports_partial_auto()
-            else make_host_mesh(data=4, model=1, pod=2))
+    mesh = make_host_mesh(data=2, model=2, pod=2)
     batch = {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
              for k, v in _batch(cfg).items()}
     for rules, hyb in [
@@ -186,3 +179,59 @@ def test_multipod_flat_and_hybrid_lower():
                            hybrid_pod=hyb, block_kv=64)
         jitted, args = build_train_artifacts(cfg, mesh, gcfg, batch)
         assert jitted.lower(*args).compile() is not None
+
+
+# ===========================================================================
+# the training driver's state: sharded init + donated steps
+# ===========================================================================
+def test_sharded_init_and_donated_steps_match_eager():
+    """``launch.train`` creates the state inside jit straight into its FSDP
+    shardings and donates it to every step: the parameters must equal
+    eager ``T.init_params`` bitwise, and two donated steps must give the
+    losses of the former path (eager init, plain jit, no donation)."""
+    from repro.core.gspmd import (init_train_state, jit_train_step,
+                                  train_batch_shardings,
+                                  train_state_shardings)
+
+    cfg = get_reduced("qwen-1.5b")
+    mesh = make_host_mesh(data=8, model=1)
+    gcfg = GSPMDConfig(rules=ShardingRules(), schedule="minibatch",
+                       comm="odc", block_kv=64)
+    opt_cfg = AdamWConfig(lr=1e-3)
+
+    params, opt = init_train_state(cfg, mesh, gcfg, KEY)
+    eager = T.init_params(cfg, KEY)
+    p_sh, o_sh = train_state_shardings(cfg, mesh, gcfg)
+    for got, want, sh in zip(jax.tree.leaves(params), jax.tree.leaves(eager),
+                             jax.tree.leaves(p_sh)):
+        assert got.sharding == sh
+        assert bool((got == want).all())
+    embed = params["embed"]  # sharded: each device holds 1/8 of it
+    assert {s.data.shape for s in embed.addressable_shards} == \
+        {(embed.shape[0], embed.shape[1] // 8)}
+    for got, want in zip(jax.tree.leaves(opt),
+                         jax.tree.leaves(adamw_init(eager))):
+        assert bool((got == want).all())
+
+    old_step = jax.jit(make_train_step(cfg, mesh, gcfg, opt_cfg))
+    new_step = jit_train_step(cfg, mesh, gcfg, opt_cfg)
+    old = (eager, adamw_init(eager))
+    new = (params, opt)
+    for i in range(2):
+        batch = _batch(cfg, M=2, Bm=8, S=32)
+        batch["tokens"] = (batch["tokens"] + i) % cfg.vocab_size
+        with mesh:
+            *old, m_old = old_step(*old, batch)
+            *new, m_new = new_step(
+                *new, jax.device_put(
+                    batch, train_batch_shardings(batch, mesh, gcfg)))
+        assert float(m_new["loss"]) == float(m_old["loss"]), i
+    assert embed.is_deleted()  # donated to the first step
+    for got, sh in zip(jax.tree.leaves(new[1]), jax.tree.leaves(o_sh)):
+        assert got.sharding == sh
+
+
+def test_roofline_peaks_are_keyed_by_device_kind():
+    assert H.peak_rates("TPU v5 lite")["flops"] == 197e12
+    with pytest.raises(ValueError, match="no published peak rates"):
+        H.peak_rates("cpu")  # an unknown device is an error, not a default

@@ -74,8 +74,8 @@ def test_odc_scatter_matches_psum_scatter(c, f, dtype):
     (3, 2, 5, jnp.float32), (2, 4, 8, jnp.bfloat16), (5, 1, 16, jnp.float32),
 ])
 def test_odc_gather_layers_matches_stacked_all_gather(L, c, f, dtype):
-    """Cross-layer double-buffered gather: L chained rings through one
-    two-slot staging pair must reproduce every layer's full tensor."""
+    """Cross-layer gather: L chained rings with no inter-layer barrier
+    must reproduce every layer's full tensor."""
     mesh = _ring_mesh()
     n = 4
     x = jax.random.normal(KEY, (L, n * c, f)).astype(dtype)
@@ -218,3 +218,17 @@ def test_ssd_scan_chunk_invariance():
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(st16), np.asarray(st64),
                                rtol=1e-4, atol=1e-4)
+
+
+# ===========================================================================
+# the one interpret switch
+# ===========================================================================
+def test_interpret_mode_follows_platform_and_is_refused_on_tpu(monkeypatch):
+    from repro.kernels import interpret_mode
+
+    assert interpret_mode(None) is True  # this host is not a TPU
+    assert interpret_mode(False) is False  # compile for a described chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert interpret_mode(None) is False
+    with pytest.raises(ValueError, match="interpret mode requested on a TPU"):
+        interpret_mode(True)

@@ -27,10 +27,7 @@ def _setup(arch):
     # big capacity factor: no token drops, so dispatch layouts can't change
     # numerics between the baseline and EP paths
     cfg = dataclasses.replace(get_reduced(arch), moe_capacity_factor=8.0)
-    from repro import compat
-    mesh = (make_host_mesh(data=4, model=2)
-            if compat.supports_partial_auto()
-            else make_host_mesh(data=8, model=1))
+    mesh = make_host_mesh(data=4, model=2)
     params = T.init_params(cfg, KEY)
     M, Bm, S = 2, 8, 32
     kb = jax.random.PRNGKey(1)

@@ -29,7 +29,6 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 from repro.balance import STRATEGIES
 from repro.core import backend as B
 from repro.data import sample_lengths
@@ -123,9 +122,9 @@ def test_per_step_ledger_and_program_scopes():
 # the comm-byte accounting seam
 # ===========================================================================
 def _shard_run(fn, mesh, in_specs, out_specs):
-    return compat.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_vma=False,
-                            axis_names=set(mesh.axis_names))
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False,
+                         axis_names=set(mesh.axis_names))
 
 
 def _real_counter_rows(backend_name, mesh, axis, spec, x, tmp_path, tag):

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro import compat
 from repro.balance import STRATEGIES
 from repro.configs import get_reduced
 from repro.core.gspmd import GSPMDConfig, ShardingRules, make_train_step
@@ -37,9 +36,7 @@ KEY = jax.random.PRNGKey(0)
 
 
 def _mesh():
-    if compat.supports_partial_auto():
-        return make_host_mesh(data=4, model=2)
-    return make_host_mesh(data=8, model=1)
+    return make_host_mesh(data=4, model=2)
 
 
 def _batch(cfg, M=2, Bm=8, S=32):

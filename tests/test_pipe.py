@@ -35,7 +35,6 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 from repro.balance import STRATEGIES
 from repro.configs import get_reduced
 from repro.core import backend as B
@@ -60,9 +59,9 @@ KEY = jax.random.PRNGKey(0)
 
 
 def _shard_run(fn, mesh, in_specs, out_specs):
-    return compat.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_vma=False,
-                            axis_names=set(mesh.axis_names))
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False,
+                         axis_names=set(mesh.axis_names))
 
 
 # ===========================================================================

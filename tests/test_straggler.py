@@ -225,7 +225,6 @@ def test_profile_ring_preserves_gather_scatter_semantics(straggler_profiles):
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro import compat
     from repro.core import odc
 
     mesh = Mesh(np.array(jax.devices()).reshape(8), ("data",))
@@ -235,8 +234,8 @@ def test_profile_ring_preserves_gather_scatter_semantics(straggler_profiles):
     x = jnp.arange(8 * 4 * 3, dtype=jnp.float32).reshape(32, 3)
 
     def run(fn, arr):
-        return jax.jit(compat.shard_map(fn, mesh=mesh, in_specs=P("data"),
-                                        out_specs=P("data")))(arr)
+        return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=P("data"),
+                                     out_specs=P("data")))(arr)
 
     g_ord = run(lambda s: odc.ring_gather(s, "data", device_profile=prof)[None], x)
     g_col = run(lambda s: odc.collective_gather(s, "data")[None], x)
