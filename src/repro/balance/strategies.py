@@ -41,6 +41,7 @@ from repro.balance.cost import (
     get_compute_costs,
 )
 from repro.balance.kk import karmarkar_karp
+from repro.obs.spans import span
 
 
 @dataclasses.dataclass
@@ -459,12 +460,13 @@ def make_plan(seqlens: Sequence[int], world_size: int, max_tokens: int, *,
     point shared by the loaders, the posttrain dispatch queue, and the
     drivers (only ``lb_mini_het`` takes a device profile and only
     ``lb_token`` takes a cp degree, so callers no longer special-case the
-    kwargs)."""
-    fn = STRATEGIES[strategy]
-    kw = {}
-    if strategy == "lb_mini_het":
-        kw["profile"] = profile
-    if strategy == "lb_token":
-        kw["cp"] = cp
-    return fn([int(l) for l in seqlens], world_size, max_tokens, cost_model,
-              **kw)
+    kwargs).  Runs under the host span ``balance.make_plan``."""
+    with span("balance.make_plan"):
+        fn = STRATEGIES[strategy]
+        kw = {}
+        if strategy == "lb_mini_het":
+            kw["profile"] = profile
+        if strategy == "lb_token":
+            kw["cp"] = cp
+        return fn([int(l) for l in seqlens], world_size, max_tokens,
+                  cost_model, **kw)

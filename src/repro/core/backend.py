@@ -272,12 +272,14 @@ class CommBackend:
                                  axis_name=axis_name,
                                  device_profile=device_profile)
 
+        @jax.named_scope("comm.gather")
         def _g(x):
             self._record_traced("gather", x, axis_name)
             if dim == 0:
                 return g_fn(x)
             return jnp.moveaxis(g_fn(jnp.moveaxis(x, dim, 0)), 0, dim)
 
+        @jax.named_scope("comm.scatter")
         def _s(y):
             self._record_traced("scatter", y, axis_name, full=True)
             if dim == 0:

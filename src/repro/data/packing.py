@@ -18,6 +18,9 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro.obs import metrics as obs_metrics
+from repro.obs.spans import span
+
 
 def pack_sequences(sample_tokens: Sequence[np.ndarray], buffer_len: int,
                    pad_id: int = 0) -> Dict[str, np.ndarray]:
@@ -85,10 +88,28 @@ def build_minibatch(plan, sample_tokens: Sequence[np.ndarray],
     ``repro.core.cp.interleave_indices`` so the engine's contiguous
     shard_map split hands each rank its head+tail chunk pair.
 
-    Returns jnp arrays, ready for a jitted train step.
+    Returns jnp arrays, ready for a jitted train step.  The numpy assembly
+    runs under the host span ``data.pack``, the copy to the device under
+    ``data.to_device``; with a metrics registry active, the counters
+    ``data.tokens_real`` (sample tokens) and ``data.token_slots`` (every
+    slot of the stack, padding included) grow by this minibatch's.
     """
+    with span("data.pack"):
+        batch = _assemble(plan, sample_tokens, buffer_len, advantages,
+                          extras, pad_id)
+    reg = obs_metrics.active()
+    if reg is not None:
+        seg = batch["segment_ids"]
+        reg.counter("data.tokens_real").inc(float(np.count_nonzero(seg >= 0)))
+        reg.counter("data.token_slots").inc(float(seg.size))
     import jax.numpy as jnp  # deferred: keep repro.data importable sans jax
 
+    with span("data.to_device"):
+        return {k: jnp.asarray(v) for k, v in batch.items()}
+
+
+def _assemble(plan, sample_tokens, buffer_len, advantages, extras, pad_id):
+    """The numpy (M, W, S) stack of :func:`build_minibatch`."""
     cp = getattr(plan, "cp", 1)
     row_len = buffer_len * cp if cp > 1 else buffer_len
     M = max(plan.max_microbatches, 1)
@@ -118,4 +139,4 @@ def build_minibatch(plan, sample_tokens: Sequence[np.ndarray],
     if extras:  # e.g. stub modality embeddings
         for k, v in extras.items():
             batch[k] = v(M, world)
-    return {k: jnp.asarray(v) for k, v in batch.items()}
+    return batch

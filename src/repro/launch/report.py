@@ -275,6 +275,11 @@ def _simulate(args) -> int:
             reg.gauge("sim.step_makespan_s").set(r.makespan)
             reg.counter("train.tokens").inc(float(sum(lens)))
             reg.counter("train.samples").inc(float(len(lens)))
+            # the padding counters build_minibatch records as it packs
+            reg.counter("data.tokens_real").inc(float(sum(lens)))
+            reg.counter("data.token_slots").inc(float(
+                max(plan.max_microbatches, 1) * plan.world_size
+                * args.max_tokens * getattr(plan, "cp", 1)))
             reg.step(t)
             # splice this step's lane events into the run timeline at the
             # current offset, so the trace covers the whole run

@@ -297,23 +297,28 @@ def main(argv=None):
         for i, step_data in enumerate(
                 loader.steps(args.steps, skip=start_step),
                 start=start_step):
-            with maybe_span(rec, "host", "compute", f"build minibatch {i}"):
-                batch = build_minibatch(step_data["plan"],
-                                        step_data["sample_tokens"],
-                                        args.max_tokens,
-                                        extras=extras_for(i))
-                batch = jax.device_put(
-                    batch, train_batch_shardings(batch, mesh, gcfg))
-            t0 = time.time()
-            with maybe_span(rec, "trainer", "compute", f"train step {i}"):
-                # program scope: a retrace (new batch shapes) REPLACES the
-                # step program's per-step comm ledger instead of stacking
-                # on the stale one
-                with obs_metrics.program("train_step"):
-                    with mesh:
-                        params, opt_state, metrics = step_fn(
-                            params, opt_state, batch)
-                loss = float(metrics["loss"])  # blocks on the device result
+            # a jax.profiler trace of the run carries each step's id
+            with jax.profiler.StepTraceAnnotation("train", step_num=i):
+                with maybe_span(rec, "host", "compute",
+                                f"build minibatch {i}"):
+                    batch = build_minibatch(step_data["plan"],
+                                            step_data["sample_tokens"],
+                                            args.max_tokens,
+                                            extras=extras_for(i))
+                    batch = jax.device_put(
+                        batch, train_batch_shardings(batch, mesh, gcfg))
+                t0 = time.time()
+                with maybe_span(rec, "trainer", "compute",
+                                f"train step {i}"):
+                    # program scope: a retrace (new batch shapes) REPLACES
+                    # the step program's per-step comm ledger instead of
+                    # stacking on the stale one
+                    with obs_metrics.program("train_step"):
+                        with mesh:
+                            params, opt_state, metrics = step_fn(
+                                params, opt_state, batch)
+                    # blocks on the device result
+                    loss = float(metrics["loss"])
             dt_step = time.time() - t0
             samples_done += len(step_data["lengths"])
             if reg is not None:

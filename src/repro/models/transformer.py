@@ -122,15 +122,17 @@ def init_params(cfg: ModelConfig, key, dtype=jnp.float32):
 # ===========================================================================
 def _apply_dense_block(cfg, lp, x, *, window, positions, segment_ids, cache,
                        cache_index, block_kv, causal=True):
-    h = L.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    a, cache = L.attn_apply(
-        cfg, lp["attn"], h, window=window, positions=positions,
-        segment_ids=segment_ids, cache=cache, cache_index=cache_index,
-        block_kv=block_kv,
-    )
+    with jax.named_scope("attention"):
+        h = L.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        a, cache = L.attn_apply(
+            cfg, lp["attn"], h, window=window, positions=positions,
+            segment_ids=segment_ids, cache=cache, cache_index=cache_index,
+            block_kv=block_kv,
+        )
     x = x + a
-    h = L.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-    x = x + L.mlp_apply(cfg, lp["mlp"], h)
+    with jax.named_scope("mlp"):
+        h = L.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+        x = x + L.mlp_apply(cfg, lp["mlp"], h)
     return x, cache
 
 
@@ -144,23 +146,27 @@ def _apply_moe_block(cfg, lp, x, *, window, positions, segment_ids, cache,
     if cache is not None and "router_counts" in cache:
         router_counts = cache["router_counts"]
         attn_cache = {k: v for k, v in cache.items() if k != "router_counts"}
-    h = L.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    a, attn_cache = L.attn_apply(
-        cfg, lp["attn"], h, window=window, positions=positions,
-        segment_ids=segment_ids, cache=attn_cache, cache_index=cache_index,
-        block_kv=block_kv,
-    )
+    with jax.named_scope("attention"):
+        h = L.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        a, attn_cache = L.attn_apply(
+            cfg, lp["attn"], h, window=window, positions=positions,
+            segment_ids=segment_ids, cache=attn_cache,
+            cache_index=cache_index, block_kv=block_kv,
+        )
     x = x + a
-    h = L.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-    if router_counts is not None:
-        ffn, aux, router_counts = moe_mod.moe_apply(
-            cfg, lp["moe"], h, groups=moe_groups,
-            router_counts=router_counts,
-            capacity_len=attn_cache["k"].shape[1])
-    else:
-        ffn, aux = moe_mod.moe_apply(cfg, lp["moe"], h, groups=moe_groups)
+    with jax.named_scope("moe"):
+        h = L.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+        if router_counts is not None:
+            ffn, aux, router_counts = moe_mod.moe_apply(
+                cfg, lp["moe"], h, groups=moe_groups,
+                router_counts=router_counts,
+                capacity_len=attn_cache["k"].shape[1])
+        else:
+            ffn, aux = moe_mod.moe_apply(cfg, lp["moe"], h,
+                                         groups=moe_groups)
     if "shared_mlp" in lp:
-        ffn = ffn + L.mlp_apply(cfg, lp["shared_mlp"], h)
+        with jax.named_scope("mlp"):
+            ffn = ffn + L.mlp_apply(cfg, lp["shared_mlp"], h)
     x = x + ffn
     new_cache = attn_cache
     if router_counts is not None:
@@ -199,6 +205,7 @@ def _static_window(cfg):
     return ws.pop() if len(ws) == 1 else None
 
 
+@jax.named_scope("lm_head")
 def _logits(cfg, params, x):
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params.get("lm_head")
@@ -210,6 +217,7 @@ def _logits(cfg, params, x):
     return logits
 
 
+@jax.named_scope("embed")
 def _embed(cfg, params, batch):
     x = jnp.take(params["embed"], batch["tokens"], axis=0)
     if cfg.frontend != "none" and cfg.frontend_tokens and "vision_embeds" in batch:
@@ -467,14 +475,17 @@ def _encode(cfg, params, encoder_embeds, enc_positions=None, remat=False,
         enc_positions = jnp.arange(S)[None, :].repeat(B, 0)
 
     def block(lp, x):
-        h = L.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        # encoder self-attention is bidirectional
-        a, _ = L.attn_apply(
-            cfg, lp["attn"], h, positions=enc_positions, causal=False, block_kv=block_kv
-        )
+        with jax.named_scope("attention"):
+            h = L.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+            # encoder self-attention is bidirectional
+            a, _ = L.attn_apply(
+                cfg, lp["attn"], h, positions=enc_positions, causal=False,
+                block_kv=block_kv,
+            )
         x = x + a
-        h = L.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + L.mlp_apply(cfg, lp["mlp"], h)
+        with jax.named_scope("mlp"):
+            h = L.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+            x = x + L.mlp_apply(cfg, lp["mlp"], h)
         return x
 
     if prefetch is not None:
@@ -519,13 +530,14 @@ def _forward_audio(cfg, params, batch, caches, cache_index, remat, block_kv,
             cache=cache, cache_index=cache_index, block_kv=block_kv,
         )
         # cross attention to the encoder output
-        h = L.rms_norm(x, lp["cross_norm"], cfg.norm_eps)
-        hd = cfg.resolved_head_dim
-        k = jnp.einsum("bsd,dk->bsk", enc_out, lp["cross"]["wk"]).reshape(B, Senc, cfg.num_kv_heads, hd)
-        v = jnp.einsum("bsd,dk->bsk", enc_out, lp["cross"]["wv"]).reshape(B, Senc, cfg.num_kv_heads, hd)
-        c, _ = L.attn_apply(
-            cfg, lp["cross"], h, positions=positions, cross_kv=(k, v), block_kv=block_kv,
-        )
+        with jax.named_scope("attention"):
+            h = L.rms_norm(x, lp["cross_norm"], cfg.norm_eps)
+            hd = cfg.resolved_head_dim
+            k = jnp.einsum("bsd,dk->bsk", enc_out, lp["cross"]["wk"]).reshape(B, Senc, cfg.num_kv_heads, hd)
+            v = jnp.einsum("bsd,dk->bsk", enc_out, lp["cross"]["wv"]).reshape(B, Senc, cfg.num_kv_heads, hd)
+            c, _ = L.attn_apply(
+                cfg, lp["cross"], h, positions=positions, cross_kv=(k, v), block_kv=block_kv,
+            )
         return x + c, cache
 
     if prefetch is not None:
@@ -607,10 +619,12 @@ def loss(cfg: ModelConfig, params, batch, *, remat: bool = False,
     mask = batch.get("loss_mask")
     if mask is None:
         mask = jnp.ones(targets.shape, jnp.float32)
-    logits = logits.astype(jnp.float32)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    tgt_logit = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    nll = (logz - tgt_logit) * mask
+    with jax.named_scope("cross_entropy"):
+        logits = logits.astype(jnp.float32)
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        tgt_logit = jnp.take_along_axis(logits, targets[..., None],
+                                        axis=-1)[..., 0]
+        nll = (logz - tgt_logit) * mask
     tokens = jnp.sum(jnp.abs(mask))
     if reduction == "sum":
         total = jnp.sum(nll) + aux * jnp.maximum(tokens, 1.0)

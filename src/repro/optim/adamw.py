@@ -34,10 +34,12 @@ def _global_norm(tree):
     )
 
 
+@jax.named_scope("adamw")
 def adamw_update(cfg: AdamWConfig, params, grads, state, lr_scale=1.0,
                  grad_norm=None):
-    """One AdamW step.  ``grad_norm`` may be supplied externally when the
-    local leaves are shards of a larger tree (pass the true global norm)."""
+    """One AdamW step, the clip included, under the ``adamw`` name scope.
+    ``grad_norm`` may be supplied externally when the local leaves are
+    shards of a larger tree (pass the true global norm)."""
     step = state["step"] + 1
     if cfg.grad_clip > 0:
         gn = _global_norm(grads) if grad_norm is None else grad_norm

@@ -10,6 +10,7 @@ time: only one process may load the TPU library, and every test worker
 imports this file.  Where it cannot be described the tests skip.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -109,3 +110,35 @@ def test_odc_ring_scatter_accumulate_compiles_at_layer_shard(ring):
     y = jax.ShapeDtypeStruct((n, n) + SHARD, jnp.float32,
                              sharding=NamedSharding(ring, P("x")))
     assert _has_kernel(jax.jit(fn).lower(y).compile())
+
+
+def test_step_program_names_every_matmul(topo):
+    """The chip's compiled training step (reduced qwen-1.5b, minibatch
+    schedule, both remat levels): every matmul carries exactly one of the
+    program's block scopes, which the benchmark's trace reduction reads."""
+    from repro.configs import get_reduced
+    from repro.core import gspmd
+
+    cfg = get_reduced("qwen-1.5b")
+    mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    gcfg = gspmd.GSPMDConfig(rules=gspmd.ShardingRules(),
+                             schedule="minibatch", comm="odc", block_kv=32)
+    shapes = {k: jax.ShapeDtypeStruct((2, 1, 64), dt) for k, dt in (
+        ("tokens", jnp.int32), ("targets", jnp.int32),
+        ("positions", jnp.int32), ("segment_ids", jnp.int32),
+        ("loss_mask", jnp.float32))}
+    jitted, args = gspmd.build_train_artifacts(cfg, mesh, gcfg, shapes)
+    hlo = jitted.lower(*args).compile().as_text()
+    blocks = {"attention", "mlp", "lm_head"}
+    matmuls = [line for line in hlo.splitlines()
+               if re.search(r" (dot|convolution)\(", line)]
+    assert len(matmuls) >= 30
+    seen = set()
+    for line in matmuls:
+        op = re.search(r'op_name="([^"]*)"', line)
+        assert op, line
+        names = {re.sub(r"^(?:[\w.-]+\()+([^()]*)\)*$", r"\1", seg)
+                 for seg in op.group(1).split("/")}
+        assert len(blocks & names) == 1, op.group(1)
+        seen |= blocks & names
+    assert seen == blocks
