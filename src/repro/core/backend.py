@@ -820,7 +820,10 @@ def build_schedule_grad(schedule: str, *, loss_sum: Callable,
                                   (schedule='minibatch'/'1f1b')
       pxform    per-layer materialization hook ('layer'/'overlap')
       prefetch  one-slot-ahead materialization hook ('overlap' only)
-      checkpoint_minibatch  remat the per-microbatch body (GSPMD engine)
+      checkpoint_minibatch  schedule='1f1b' only: remat each microbatch's
+                forward (GSPMD engine), whose residuals would otherwise wait
+                in flight for its backward; the other schedules run each
+                microbatch's forward and backward back to back
       pipe_stages / pipe_interleave  schedule='1f1b' only: the pipeline
                 depth whose stage-0 ``instructions_1f1b`` order the
                 microbatch forwards/backwards are issued in, and the
@@ -890,28 +893,17 @@ def build_schedule_grad(schedule: str, *, loss_sum: Callable,
             raise ValueError("schedule='minibatch' needs a gather_all hook")
 
         def grad_core(params_local, microbatches):
-            # ODC placement: gather each parameter once per minibatch;
-            # gradients accumulate LOCALLY across microbatches (no
-            # collective in the loop) and AD emits exactly one
-            # scatter-accumulate per parameter at the minibatch end
-            # (paper Fig. 2).
-            def total_loss(pl):
-                full = gather_all(pl)
-
-                def body(carry, mb):
-                    lsum, tok = carry
-                    l, t = loss_sum(full, mb, None, None)
-                    return (lsum + l, tok + t), None
-
-                scan_body = jax.checkpoint(body) if checkpoint_minibatch \
-                    else body
-                (lsum, tok), _ = jax.lax.scan(
-                    scan_body, (jnp.float32(0.0), jnp.float32(0.0)),
-                    microbatches)
-                return lsum, tok
-
-            (lsum, tok), grads = jax.value_and_grad(
-                total_loss, has_aux=True)(params_local)
+            # ODC placement: gather each parameter once per minibatch
+            # (through jax.vjp); gradients accumulate LOCALLY across
+            # microbatches (no collective in the loop), and pulling the sum
+            # back through the gather emits exactly one scatter-accumulate
+            # per parameter at the minibatch end (paper Fig. 2).  Each
+            # microbatch's forward runs once, right before its backward.
+            full, gather_vjp = jax.vjp(gather_all, params_local)
+            lsum, tok, grad_full = _accumulate_grads(
+                lambda fp, mb: loss_sum(fp, mb, None, None),
+                full, microbatches)
+            (grads,) = gather_vjp(grad_full)
             return lsum, tok, grads
 
         return grad_core
@@ -925,18 +917,30 @@ def build_schedule_grad(schedule: str, *, loss_sum: Callable,
     pf = prefetch if schedule == "overlap" else None
 
     def grad_core(params_local, microbatches):
-        gfun = jax.value_and_grad(
-            lambda pl, mb: loss_sum(pl, mb, pxform, pf), has_aux=True)
-
-        def body(carry, mb):
-            lsum, tok, gacc = carry
-            (l, t), g = gfun(params_local, mb)
-            gacc = jax.tree.map(jnp.add, gacc, g)
-            return (lsum + l, tok + t, gacc), None
-
-        zeros = jax.tree.map(jnp.zeros_like, params_local)
-        (lsum, tok, grads), _ = jax.lax.scan(
-            body, (jnp.float32(0.0), jnp.float32(0.0), zeros), microbatches)
-        return lsum, tok, grads
+        return _accumulate_grads(
+            lambda pl, mb: loss_sum(pl, mb, pxform, pf),
+            params_local, microbatches)
 
     return grad_core
+
+
+def _accumulate_grads(loss_fn: Callable, params, microbatches):
+    """Scan the microbatches: each one's loss and gradient in one
+    value_and_grad, the gradients summed in a carry.
+
+      loss_fn(params, mb) -> (nll_sum, token_count)
+
+    Returns (lsum, tok, grads); zeros for M == 0.
+    """
+    gfun = jax.value_and_grad(loss_fn, has_aux=True)
+
+    def body(carry, mb):
+        lsum, tok, gacc = carry
+        (l, t), g = gfun(params, mb)
+        gacc = jax.tree.map(jnp.add, gacc, g)
+        return (lsum + l, tok + t, gacc), None
+
+    zeros = jax.tree.map(jnp.zeros_like, params)
+    (lsum, tok, grads), _ = jax.lax.scan(
+        body, (jnp.float32(0.0), jnp.float32(0.0), zeros), microbatches)
+    return lsum, tok, grads

@@ -653,8 +653,11 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, gcfg: GSPMDConfig,
 
     # the schedule loop (gather placement) is the shared seam with the flat
     # engine — repro.core.backend.build_schedule_grad — fed this engine's
-    # gather/prefetch hooks; the minibatch scan body is rematerialized here
-    # (full-model gradient residency is the ODC trade, not activations)
+    # gather/prefetch hooks.  The minibatch schedule runs each microbatch's
+    # forward once, right before its backward, so only the model's own
+    # per-layer remat recomputes; the checkpoint flag remats the forwards
+    # that 1f1b keeps in flight (full-model gradient residency is the ODC
+    # trade, not activations)
     grad_core = B.build_schedule_grad(
         schedule,
         loss_sum=loss_sum,

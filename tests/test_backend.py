@@ -16,6 +16,7 @@ Key claims:
     ODC on a single node.
 """
 import os
+import re
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
@@ -73,6 +74,78 @@ def test_build_schedule_grad_validation():
         B.build_schedule_grad("epoch", loss_sum=lambda *a: (0.0, 0.0))
     with pytest.raises(ValueError, match="gather_all"):
         B.build_schedule_grad("minibatch", loss_sum=lambda *a: (0.0, 0.0))
+
+
+def _mlp_loss(p, mb, px, prefetch=None):
+    h = jnp.tanh(mb["x"] @ p["w1"]) @ p["w2"]
+    return jnp.sum(mb["m"][:, None] * h ** 2), jnp.sum(mb["m"])
+
+
+def _stack_gather(pl):
+    """A stand-in for a parameter gather: a linear map onto bigger leaves,
+    so the gradient has to be pulled back through it."""
+    return jax.tree.map(lambda v: jnp.concatenate([v, 2.0 * v]), pl)
+
+
+@pytest.mark.parametrize("M", [0, 1, 3])
+def test_minibatch_schedule_is_the_gradient_of_the_summed_loss(M):
+    """The minibatch loop (each microbatch's value_and_grad once, summed
+    in a carry, pulled back through the gather once) gives what jax.grad
+    of the plain sum of the per-microbatch losses gives."""
+    rng = np.random.default_rng(M)
+    params = {"w1": jnp.asarray(rng.normal(size=(4, 16)), jnp.float32),
+              "w2": jnp.asarray(rng.normal(size=(8, 8)), jnp.float32)}
+    mbs = {"x": jnp.asarray(rng.normal(size=(M, 5, 8)), jnp.float32),
+           "m": jnp.asarray(rng.integers(0, 2, size=(M, 5)), jnp.float32)}
+
+    def plain(pl):
+        full = _stack_gather(pl)
+        return jnp.float32(0.0) + sum(
+            _mlp_loss(full, jax.tree.map(lambda x: x[j], mbs), None)[0]
+            for j in range(M))
+
+    lsum, tok, grads = jax.jit(B.build_schedule_grad(
+        "minibatch", loss_sum=_mlp_loss, gather_all=_stack_gather,
+        checkpoint_minibatch=True))(params, mbs)
+    ref_l, ref_g = jax.value_and_grad(plain)(params)
+    np.testing.assert_allclose(float(lsum), float(ref_l), rtol=1e-6)
+    assert float(tok) == float(mbs["m"].sum())
+    for k in params:
+        assert grads[k].shape == params[k].shape
+        ref = np.asarray(ref_g[k])  # float32 sums in another order
+        np.testing.assert_allclose(np.asarray(grads[k]), ref, rtol=1e-5,
+                                   atol=1e-6 * np.abs(ref).max())
+
+
+@pytest.mark.parametrize("M", [1, 3])
+def test_odc_minibatch_step_moves_each_parameter_once(M):
+    """A 4-device ``odc`` minibatch step gathers every parameter leaf once
+    and scatter-accumulates its gradient once, both outside the
+    microbatch loop: one ring of world - 1 hops each, 2 x 3 x 11 = 66
+    collective-permutes a step whatever M."""
+    cfg = get_reduced("qwen-1.5b")
+    world = 4
+    mesh = Mesh(np.asarray(jax.devices()[:world]).reshape(world, 1),
+                ("data", "model"))
+    gcfg = GSPMDConfig(rules=ShardingRules(), schedule="minibatch",
+                       comm="odc", block_kv=32)
+    shapes = {k: jax.ShapeDtypeStruct((M, world, 64), dt) for k, dt in (
+        ("tokens", jnp.int32), ("targets", jnp.int32),
+        ("positions", jnp.int32), ("segment_ids", jnp.int32),
+        ("loss_mask", jnp.float32))}
+    jitted, args = build_train_artifacts(cfg, mesh, gcfg, shapes)
+    text = jitted.lower(*args).compile().as_text()
+    leaves = len(jax.tree.leaves(args[0]))
+    assert leaves == 11
+    permutes = {"comm.gather": 0, "comm.scatter": 0}
+    for line in text.splitlines():
+        if re.search(r" collective-permute(-start)?\(", line):
+            op = re.search(r'op_name="([^"]*)"', line).group(1)
+            (kind,) = [k for k in permutes if k in op]
+            permutes[kind] += 1
+    assert permutes == {"comm.gather": leaves, "comm.scatter": leaves}
+    executed = H.analyze_hlo_text(text).coll_count["collective-permute"]
+    assert executed == 2 * (world - 1) * leaves == 66
 
 
 def test_sim_discipline_vocabulary():

@@ -11,6 +11,8 @@ imports this file.  Where it cannot be described the tests skip.
 """
 import os
 import re
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -112,10 +114,10 @@ def test_odc_ring_scatter_accumulate_compiles_at_layer_shard(ring):
     assert _has_kernel(jax.jit(fn).lower(y).compile())
 
 
-def test_step_program_names_every_matmul(topo):
-    """The chip's compiled training step (reduced qwen-1.5b, minibatch
-    schedule, both remat levels): every matmul carries exactly one of the
-    program's block scopes, which the benchmark's trace reduction reads."""
+@pytest.fixture(scope="module")
+def step_hlo(topo):
+    """The chip's compiled training step: reduced qwen-1.5b on one chip,
+    minibatch schedule, M = 2 microbatches of 64 tokens."""
     from repro.configs import get_reduced
     from repro.core import gspmd
 
@@ -128,9 +130,15 @@ def test_step_program_names_every_matmul(topo):
         ("positions", jnp.int32), ("segment_ids", jnp.int32),
         ("loss_mask", jnp.float32))}
     jitted, args = gspmd.build_train_artifacts(cfg, mesh, gcfg, shapes)
-    hlo = jitted.lower(*args).compile().as_text()
+    return jitted.lower(*args).compile().as_text()
+
+
+def test_step_program_names_every_matmul(step_hlo):
+    """Every matmul of the chip's compiled training step carries exactly
+    one of the program's block scopes, which the benchmark's trace
+    reduction reads."""
     blocks = {"attention", "mlp", "lm_head"}
-    matmuls = [line for line in hlo.splitlines()
+    matmuls = [line for line in step_hlo.splitlines()
                if re.search(r" (dot|convolution)\(", line)]
     assert len(matmuls) >= 30
     seen = set()
@@ -142,3 +150,21 @@ def test_step_program_names_every_matmul(topo):
         assert len(blocks & names) == 1, op.group(1)
         seen |= blocks & names
     assert seen == blocks
+
+
+def test_minibatch_step_recomputes_only_the_layers(step_hlo):
+    """The compiled minibatch step's matmul FLOPs by pass: each
+    microbatch's forward runs once, so the only recompute is the layer
+    checkpoint's.  It redoes attention whole and the MLP's gate and up
+    projections (the down projection's output no backward reads), and
+    nothing of the head."""
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+    from bench.tests.test_scopes import matmul_flops
+
+    own, _ = matmul_flops(step_hlo)
+    assert own["forward/attention"] > 0 and own["forward/mlp"] > 0
+    assert own["recompute/attention"] == own["forward/attention"]
+    assert 3 * own["recompute/mlp"] == 2 * own["forward/mlp"]
+    assert own["recompute/lm_head"] == 0
+    for b in ("attention", "mlp", "lm_head"):
+        assert own[f"backward/{b}"] == 2 * own[f"forward/{b}"]
