@@ -8,8 +8,11 @@ through :meth:`Program.step`, which does what ``repro.launch.train`` does:
 ``make_plan``, ``build_minibatch``, ``jax.device_put`` onto the step's
 batch shardings, the step, and a wait on its loss.
 
-Configuration, traffic, limits and metric readers are files found by name
-(``bench/configs``, ``bench/traffic``, ``bench/limits``, ``bench/metrics``).
+Configuration, traffic, limits, metric readers and the plain reference
+are files found by name (``bench/configs``, ``bench/traffic``,
+``bench/limits``, ``bench/metrics``, ``bench/reference``): the
+configuration file names its reference module, which owns everything that
+depends on the architecture (``bench/reference/__init__.py``).
 """
 from __future__ import annotations
 
@@ -43,13 +46,20 @@ from repro.data.packing import build_minibatch  # noqa: E402
 from repro.obs import metrics as obs_metrics  # noqa: E402
 from repro.optim import AdamWConfig, adamw_init  # noqa: E402
 
-from bench.harness import check, flops, peaks, trace, traffic  # noqa: E402
-from bench.reference import dense_decoder as ref  # noqa: E402
+from bench.harness import check, peaks, scopes, trace, traffic  # noqa: E402
 
 FIRST_STEPS = 3  # the steps the reference follows
 BATCH_KEYS = {"tokens": jnp.int32, "targets": jnp.int32,
               "positions": jnp.int32, "segment_ids": jnp.int32,
               "loss_mask": jnp.float32}
+REFERENCES = BENCH / "reference"
+# a configuration's published key -> the program's ModelConfig field
+MODEL_KEYS = {"num_hidden_layers": "num_layers", "hidden_size": "d_model",
+              "num_attention_heads": "num_heads",
+              "num_key_value_heads": "num_kv_heads", "head_dim": "head_dim",
+              "intermediate_size": "d_ff", "vocab_size": "vocab_size",
+              "rms_norm_eps": "norm_eps", "rope_theta": "rope_theta",
+              "tie_word_embeddings": "tie_embeddings"}
 
 
 # ---------------------------------------------------------------------------
@@ -64,6 +74,11 @@ class Cell:
     limits: Optional[dict]  # bench/limits/<workload>.json
     end_to_end: List[dict]  # BENCHMARK.json entries this cell reports
     per_layer: List[dict]
+
+    @property
+    def ref(self):
+        """The configuration's reference module."""
+        return reference_module(self.config["reference"])
 
 
 def load_cell(workload: str) -> Cell:
@@ -84,27 +99,55 @@ def load_cell(workload: str) -> Cell:
 def cell_from_files(workload: str, config_file: Path, mix: str, chips: int,
                     end_to_end=(), per_layer=()) -> Cell:
     limits_path = check.DIR / f"{workload}.json"
-    return Cell(workload, json.loads(Path(config_file).read_text()),
+    cell = Cell(workload, json.loads(Path(config_file).read_text()),
                 traffic.load(mix), chips,
                 check.load_limits(workload) if limits_path.exists() else None,
                 list(end_to_end), list(per_layer))
+    cell.ref  # an unknown reference fails here, before anything compiles
+    return cell
+
+
+def reference_module(name: str):
+    """``bench/reference/<name>.py``, imported once as
+    ``bench.reference.<name>``."""
+    path = (REFERENCES / f"{name}.py").resolve()
+    if not name.isidentifier() or not path.is_file():
+        known = sorted(p.stem for p in REFERENCES.glob("[!_]*.py"))
+        raise ValueError(f"unknown reference {name!r}: no {path}; known: "
+                         f"{known}")
+    qualified = f"bench.reference.{name}"
+    mod = sys.modules.get(qualified)
+    if mod is None or Path(mod.__file__).resolve() != path:
+        spec = importlib.util.spec_from_file_location(qualified, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[qualified] = mod
+        spec.loader.exec_module(mod)
+    return mod
 
 
 def model_config(conf: dict):
-    """The program's ModelConfig: the registry entry with every size the
-    configuration file runs at."""
-    run = conf["run"]
+    """The program's ModelConfig: the registry entry with the sizes the
+    configuration file runs at (each key of ``MODEL_KEYS`` that ``run``
+    has), then the file's ``program`` object (ModelConfig fields and their
+    values).  The registry family and activation have to be ones the
+    configuration's reference implements."""
     base = get_config(conf["registry"])
-    if base.family != "dense" or base.activation != "swiglu":
-        raise ValueError(f"{conf['registry']}: the reference is a dense "
-                         "SwiGLU decoder")
-    return dataclasses.replace(
-        base, num_layers=run["num_hidden_layers"], d_model=run["hidden_size"],
-        num_heads=run["num_attention_heads"],
-        num_kv_heads=run["num_key_value_heads"], head_dim=run["head_dim"],
-        d_ff=run["intermediate_size"], vocab_size=run["vocab_size"],
-        norm_eps=run["rms_norm_eps"], rope_theta=run["rope_theta"],
-        tie_embeddings=run["tie_word_embeddings"])
+    fields = {f.name for f in dataclasses.fields(base)}
+    program = conf.get("program", {})
+    unknown = sorted(set(program) - fields)
+    if unknown:
+        raise ValueError(f"{conf['name']}: 'program' names no ModelConfig "
+                         f"field {unknown}")
+    sizes = {MODEL_KEYS[k]: v for k, v in conf["run"].items()
+             if k in MODEL_KEYS}
+    cfg = dataclasses.replace(base, **{**sizes, **program})
+    families = reference_module(conf["reference"]).FAMILIES
+    if families.get(cfg.family) != cfg.activation:
+        raise ValueError(
+            f"{conf['name']}: the reference {conf['reference']!r} implements "
+            f"{families} (family: activation), not {cfg.family}: "
+            f"{cfg.activation}")
+    return cfg
 
 
 def use_compile_cache():
@@ -144,6 +187,7 @@ class Program:
 
     def __init__(self, cell: Cell, devices):
         self.cell = cell
+        self.ref = ref = cell.ref
         self.world = len(devices)
         self.S = cell.mix["microbatch_tokens"]
         self.mesh = Mesh(np.asarray(devices).reshape(self.world, 1),
@@ -180,7 +224,7 @@ class Program:
         counters the program emits while it traces."""
         stand_in = lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype,
                                                       sharding=sh)
-        p_shape = jax.eval_shape(lambda: ref.init_params(
+        p_shape = jax.eval_shape(lambda: self.ref.init_params(
             self.shape, np.zeros(2, np.uint32)))
         o_shape = jax.eval_shape(adamw_init, p_shape)
         b_shape = {k: jax.ShapeDtypeStruct((m, self.world, self.S), dt)
@@ -207,7 +251,8 @@ class Program:
         return out
 
     def init(self, seed: int):
-        self.params, self.opt_state = self._init_state(ref.seed_key_data(seed))
+        self.params, self.opt_state = self._init_state(
+            self.ref.seed_key_data(seed))
 
     def prepare(self, step: traffic.Step):
         with jax.profiler.TraceAnnotation("bench.plan"):
@@ -239,15 +284,15 @@ class Program:
     def grad_norms(self) -> Dict[str, float]:
         """The first step's clipped gradient per leaf, from AdamW's first
         moment after one step: m = (1 - b1) g."""
-        sq = ref.leaf_sq_norms(self.opt_state["m"])
+        sq = self.ref.leaf_sq_norms(self.opt_state["m"])
         return {k: v / (1.0 - self.opt.b1)
-                for k, v in ref.sq_to_norms(sq).items()}
+                for k, v in self.ref.sq_to_norms(sq).items()}
 
     def change_norms(self, seed: int) -> Dict[str, float]:
-        p0 = self._init_params(ref.seed_key_data(seed))
-        sq = ref.change_sq_norms(self.params, p0)
+        p0 = self._init_params(self.ref.seed_key_data(seed))
+        sq = self.ref.change_sq_norms(self.params, p0)
         del p0
-        return ref.sq_to_norms(sq)
+        return self.ref.sq_to_norms(sq)
 
     def free(self):
         self.params = self.opt_state = None
@@ -272,7 +317,7 @@ def first_steps(prog: Program, cycle, seed: int):
 
 
 def reference(cell: Cell, devices, dtype=jnp.float32, precision="highest"):
-    o = cell.config["optimizer"]
+    ref, o = cell.ref, cell.config["optimizer"]
     return ref.Reference(ref.Shape.from_config(cell.config["run"]),
                          ref.AdamW(**o), cell.mix["microbatch_tokens"],
                          devices, dtype=dtype, precision=precision)
@@ -294,9 +339,11 @@ class Context:
     S: int
     hbm_peak_bytes: int
     trace: Optional[dict]
+    step_flops: Callable  # the reference module's (run, lengths) -> FLOPs
+    scopes: Optional[dict] = None  # scopes.reduce of the traced window
 
     def model_flops(self) -> float:
-        return sum(flops.step_flops(self.run, s.lengths) for s in self.steps)
+        return sum(self.step_flops(self.run, s.lengths) for s in self.steps)
 
 
 class CompileCounter:
@@ -313,10 +360,27 @@ class CompileCounter:
             self.n += 1
 
 
+def scope_split(pd, programs: Dict[int, Dict[str, str]], order: List[int],
+                module: str, log=print) -> Optional[dict]:
+    """``scopes.reduce`` of the traced window, or None where the window's
+    steps and its module events differ in count: then no device op can be
+    placed in its program, and the split would put the whole step under
+    ``unscoped``."""
+    found = scopes.reduce(pd, programs, order, module=module)
+    if found is not None and found["steps_matched"] != len(order):
+        log(f"[bench] scopes: {len(order)} steps in the window, "
+            f"{found['module_events_in_window']} {module} module events "
+            f"start in it; {found['steps_matched']} steps matched, no split")
+        return None
+    return found
+
+
 def run(cell: Cell, seed: int, seconds: float, traced: bool, devices,
-        started: float, log=print) -> dict:
+        started: float, log=print, keep: Optional[Path] = None) -> dict:
     """One run; returns the result line's object.  ``started`` is the
-    process's start on ``time.perf_counter``'s clock."""
+    process's start on ``time.perf_counter``'s clock.  ``keep``: a
+    directory where a traced run writes its window's first steps
+    (``scopes.keep``)."""
     compiles = CompileCounter()
     prog = Program(cell, devices)
     cycle = traffic.steps(cell.mix, prog.world, seed, prog.cfg.vocab_size)
@@ -354,12 +418,28 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, devices,
                 break
     window_s = time.perf_counter() - t0
     in_window = compiles.n - before
-    summary = None
+    summary = found = None
     if traced:
         jax.profiler.stop_trace()
         pb = sorted(trace_dir.rglob("*.xplane.pb"))
-        summary = trace.reduce(trace.read(str(pb[-1]))) if pb else None
+        pd = trace.read(str(pb[-1])) if pb else None
         shutil.rmtree(trace_dir, ignore_errors=True)
+        summary = trace.reduce(pd) if pd is not None else None
+        if summary is not None:
+            hlo = {m: c.as_text() for m, c in prog.compiled.items()}
+            programs = {m: scopes.op_names(t) for m, t in hlo.items()}
+            module = scopes.module_name(next(iter(hlo.values())))
+            order = [r.m for r in records]
+            found = scope_split(pd, programs, order, module, log)
+            if found is not None:
+                log(f"[bench] scopes: {found['steps_matched']} steps matched;"
+                    f" busy {found['busy_s']} s (trace {summary['busy_s']}),"
+                    f" phases {found['phases_s']}; largest unscoped ops "
+                    f"{found['unscoped_ops'][:5]}")
+                if keep is not None:
+                    scopes.keep(pd, programs, order, module, keep,
+                                cell.workload)
+        del pd  # the window's whole trace, before the reference runs
     full_gcs = gc.get_stats()[2]["collections"] - full_gcs
     gc.unfreeze()
     log(f"[bench] window {window_s:.3f} s, {len(records)} steps, backend "
@@ -392,11 +472,11 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, devices,
 
     dev = devices[0]
     ctx = Context(run=cell.config["run"], chips=len(devices),
-                  peaks=peaks.lookup(dev.device_kind)
-                  if dev.platform == "tpu" else None,
+                  peaks=peaks.of(dev),
                   setup_s=setup_s, window_s=window_s, steps=records,
                   S=cell.mix["microbatch_tokens"],
-                  hbm_peak_bytes=hbm, trace=summary)
+                  hbm_peak_bytes=hbm, trace=summary,
+                  step_flops=cell.ref.step_flops, scopes=found)
     metrics = {}
     for m in (cell.per_layer if traced else cell.end_to_end):
         v = reader(m["name"])(ctx)
@@ -413,6 +493,8 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, devices,
         device.update(busy_s=summary["busy_s"], window_s=summary["window_s"])
         out["breakdown"] = {"device_ops": summary["device_ops"],
                             "idle_gaps": summary["idle_gaps"]}
+        if found is not None:
+            out["breakdown"]["device_scopes"] = found["device_scopes"]
     out["compared"] = {k: {"value": c["value"], "limit": c["limit"]}
                        for k, c in compared.items()}
     return out

@@ -6,6 +6,8 @@ without a row is an error, never a default.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 PEAKS = {
     "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
                     "hbm_bytes_per_s": 819e9, "hbm_bytes": 16e9},
@@ -18,3 +20,8 @@ def lookup(device_kind: str) -> dict:
     except KeyError:
         raise ValueError(f"no published peaks for device kind "
                          f"{device_kind!r}; known: {sorted(PEAKS)}") from None
+
+
+def of(device) -> Optional[dict]:
+    """The peaks of the device's kind; None off a TPU."""
+    return lookup(device.device_kind) if device.platform == "tpu" else None

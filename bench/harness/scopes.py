@@ -26,10 +26,11 @@ wrappers, and whole segments only, never substrings.
 Device operations (``XLA Ops`` of each ``/device:TPU:<n>``) are named by
 instruction, and one name means different things in different compiled
 programs, so each is first placed in the module it ran in: the
-``XLA Modules`` event of its device that holds it.  The window's module
-events are matched, in time order, to the programs the window's steps
-ran (one per step: the step's microbatch count); where their counts
-disagree, no op is placed and all count unscoped.  Time is that of the
+``XLA Modules`` event of its device that holds it.  The module events
+that start inside the window are matched, in time order, to the programs
+the window's steps ran (one per step: the step's microbatch count); where
+their counts disagree, no op is placed and all count unscoped
+(``bench/harness/cell.py::scope_split`` then reports no split).  Time is that of the
 childless operations inside ``bench.window``, as ``trace.reduce`` counts
 busy time: where several run at once, the time they overlap is split
 evenly between them, so that the parts add up to busy time.  Every number
@@ -38,8 +39,10 @@ is averaged over the devices.
 from __future__ import annotations
 
 import collections
+import json
 import math
 import re
+from pathlib import Path
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
@@ -307,11 +310,11 @@ def split_evenly(ops) -> Dict[object, float]:
 
 
 def step_events(mods, lo, hi, module: str, steps: int) -> List[tuple]:
-    """A device's module events in [lo, hi), one per step of the window:
-    those named for the steps' program (``<module>(<id>)``), else all of
-    them; [] where neither count is ``steps``."""
+    """A device's module events that start in [lo, hi), one per step of
+    the window: those named for the steps' program (``<module>(<id>)``),
+    else all of them; [] where neither count is ``steps``."""
     inside = sorted((s, e, name.split("(", 1)[0]) for s, e, name in mods
-                    if s < hi and e > lo)
+                    if lo <= s < hi)
     for cand in ([m for m in inside if m[2] == module], inside):
         if len(cand) == steps:
             return [(s, e) for s, e, _ in cand]
@@ -339,13 +342,13 @@ def reduce(pd, programs: Dict[object, Dict[str, str]], order: Sequence,
     n = len(devices)
     by_label: Dict[str, float] = collections.Counter()
     by_op: Dict[tuple, float] = collections.Counter()
-    matched_steps, seen = [], set()
+    matched_steps, named = [], []
     for ops, mods in devices.values():
         ops = [(max(s, lo), min(e, hi), name) for s, e, name in ops
                if e > lo and s < hi]
         leaves, _ = trace.nest(ops)
-        seen |= {name.split("(", 1)[0] for s, e, name in mods
-                 if s < hi and e > lo}
+        named.append(sum(1 for s, _, name in mods if lo <= s < hi
+                         and name.split("(", 1)[0] == module))
         steps = step_events(mods, lo, hi, module, len(order))
         # where the counts disagree no op can be placed: all count unscoped
         keyed = list(order) if steps else None
@@ -374,16 +377,81 @@ def reduce(pd, programs: Dict[object, Dict[str, str]], order: Sequence,
         "window_s": (hi - lo) * ns,
         "busy_s": busy,
         "steps_matched": min(matched_steps),
-        "module_events": sorted(seen)[:top],
+        "module_events_in_window": named,
         "phases_s": phases,
         "scopes_s": {k: v * ns for k, v in by_label.items()},
         "device_scopes": [[k, v * ns] for k, v in
                           collections.Counter(by_label).most_common(top)],
-        "device_ops": [[name, lab, v * ns] for (name, lab), v in
-                       collections.Counter(by_op).most_common(top)],
         "unscoped_ops": [[name, v * ns] for (name, lab), v in
                          collections.Counter(by_op).most_common()
                          if lab.startswith(UNSCOPED)][:top],
         "to_device_s": to_device * ns,
     }
 
+
+# ---------------------------------------------------------------------------
+# a small recorded trace, for the tests
+# ---------------------------------------------------------------------------
+def keep(pd, programs, order, module: str, out: Path, workload: str,
+         steps: int = 3):
+    """The first ``steps`` steps of the traced window as a small trace,
+    ``<out>/<workload>.xplane.pb`` (the ``XLA Ops`` and ``XLA Modules``
+    lines and the host spans the reductions read), and beside it
+    ``<workload>.scopes.json``: the programs' module name, the programs
+    its steps ran, in order, and each program's instruction -> op_name
+    path for the instructions in it.  ``bench/tests`` reads such a pair."""
+    from jax.profiler import ProfileData
+
+    devices, spans = events(pd)
+    win = [(s, e) for s, e, n in spans if n == trace.WINDOW_SPAN]
+    lo, end = win[0]
+    planes, used = [], {}
+    hi = None
+    for plane, (ops, mods) in sorted(devices.items()):
+        marks = step_events(mods, lo, end, module, len(order))
+        hi = marks[steps - 1][1] if hi is None else hi
+        ops = [o for o in ops if lo <= o[0] and o[1] <= hi]
+        for m, (s, e) in zip(order, marks[:steps]):
+            used.setdefault(m, set()).update(
+                n for a, b, n in ops if s <= a and b <= e)
+        planes.append((plane, {
+            "XLA Ops": ops,
+            "XLA Modules": [m for m in mods if lo <= m[0] and m[1] <= hi]}))
+    host = [(max(s, lo), min(e, hi), n) for s, e, n in spans
+            if s < hi and e > lo]
+    planes.append(("/host:CPU", {"python": host}))
+    text = xspace_text(planes, lo)
+    out.mkdir(parents=True, exist_ok=True)
+    (out / f"{workload}.xplane.pb").write_bytes(
+        ProfileData.text_proto_to_serialized_xspace(text))
+    (out / f"{workload}.scopes.json").write_text(json.dumps({
+        "module": module, "order": [int(m) for m in order[:steps]],
+        "programs": {str(m): {n: programs[m][n] for n in sorted(names)
+                              if n in programs[m]}
+                     for m, names in used.items()},
+    }, indent=0, sort_keys=True))
+
+
+def xspace_text(planes, t0: int) -> str:
+    """A text-format XSpace of [(plane, {line: [(start_ns, end_ns,
+    name)]})], times from ``t0``."""
+    t0 = round(t0)
+    names = sorted({n for _, lines in planes for evs in lines.values()
+                    for _, _, n in evs})
+    ids = {n: i + 1 for i, n in enumerate(names)}
+    out = []
+    for k, (plane, lines) in enumerate(planes):
+        body = []
+        for j, (line, evs) in enumerate(lines.items()):
+            ev = "".join(
+                f"events {{ metadata_id: {ids[n]} offset_ps: "
+                f"{round((s - t0) * 1000)} duration_ps: "
+                f"{round((e - s) * 1000)} }}\n" for s, e, n in evs)
+            body.append(f'lines {{ id: {j + 1} name: "{line}" '
+                        f"timestamp_ns: {round(t0)}\n{ev}}}\n")
+        used = {n for evs in lines.values() for _, _, n in evs}
+        meta = "".join(f'event_metadata {{ key: {ids[n]} value {{ id: '
+                       f'{ids[n]} name: "{n}" }} }}\n' for n in sorted(used))
+        out.append(f'planes {{ id: {k + 1} name: "{plane}"\n'
+                   + "".join(body) + meta + "}\n")
+    return "".join(out)

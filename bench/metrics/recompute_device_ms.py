@@ -1,7 +1,8 @@
-"""Device milliseconds a step in the step program's recompute phase, both
-levels of remat (the minibatch body and each layer): the self time of its
-operations in the traced window (``bench/harness/scopes.py``), over the
-steps the window completed.  None off a trace."""
+"""Device milliseconds a step in the step program's recompute phase, every
+level of remat (the minibatch schedule has one, the layer checkpoint):
+the self time of its operations in the traced window
+(``bench/harness/scopes.py``), over the steps the window completed.  None
+off a trace."""
 
 
 def read(ctx):

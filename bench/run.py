@@ -1,14 +1,17 @@
 #!/usr/bin/env python3
 """Run one benchmark cell once on the chips of this machine.
 
-    python3 bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
+    python3 bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1> \
+        [--keep <dir>]
 
 Set-up (weights from the seed, the program's step compiled for every
 microbatch count the cell's traffic reaches, the first three steps) is
 timed as ``setup_s``; then the window trains for ``--seconds``.  With
 ``--trace 0`` the result carries the cell's end-to-end metrics, with
 ``--trace 1`` its per-layer metrics, read from a profiler trace of the
-window.  After the window the plain reference retrains the first three
+window and reduced by the step program's own names
+(``bench/harness/scopes.py``); ``--keep`` then also writes the window's
+first three steps as a small trace for ``bench/tests``.  After the window the plain reference retrains the first three
 steps and ``correct`` says whether the program agreed with it within the
 cell's limits (``bench/harness/check.py``).
 
@@ -43,6 +46,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--keep", default="")
     args = ap.parse_args(argv)
 
     if not (ROOT / "src" / "repro").is_dir():
@@ -66,7 +70,8 @@ def main(argv=None) -> int:
         return fail(f"no limits file for {args.workload}")
     out = C.run(spec, args.seed, args.seconds, bool(args.trace),
                 devices[:spec.chips], STARTED,
-                log=lambda s: print(s, file=sys.stderr, flush=True))
+                log=lambda s: print(s, file=sys.stderr, flush=True),
+                keep=Path(args.keep) if args.keep else None)
     for k, c in out["compared"].items():
         print(f"{k}: {c['value']!r} (limit {c['limit']!r})", file=sys.stderr)
     sys.stderr.flush()

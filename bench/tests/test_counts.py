@@ -2,6 +2,7 @@
 import numpy as np
 
 from bench.harness import cell as C, traffic
+from bench.reference import dense_decoder
 
 
 def reader(name):
@@ -10,7 +11,8 @@ def reader(name):
 
 def ctx(steps, chips=2, S=8, **kw):
     base = dict(run={}, chips=chips, peaks=None, setup_s=1.0, window_s=2.0,
-                steps=steps, S=S, hbm_peak_bytes=3e9, trace=None)
+                steps=steps, S=S, hbm_peak_bytes=3e9, trace=None,
+                step_flops=dense_decoder.step_flops)
     base.update(kw)
     return C.Context(**base)
 

@@ -1,8 +1,15 @@
-"""The model-FLOP function against a count by hand, for both models."""
+"""The model-FLOP function against a count by hand, for both models, and
+what each configuration file builds: the program's ModelConfig and the
+FLOPs that ``mfu`` reads, through the configuration's reference module."""
+import dataclasses
 import json
 from pathlib import Path
 
-from bench.harness import flops
+import pytest
+
+from bench.harness import cell as C
+from bench.reference import dense_decoder as flops
+from repro.configs import get_config
 
 CONFIGS = Path(__file__).resolve().parents[1] / "configs"
 
@@ -41,3 +48,54 @@ def test_packing_does_not_change_model_flops():
     r = run("qwen-1.5b-d12-fsdp4")
     assert flops.step_flops(r, [10, 20]) == (flops.step_flops(r, [10])
                                              + flops.step_flops(r, [20]))
+
+
+# the ModelConfig each configuration built before its reference module
+# chose it, by hand: the registry entry with every size of the file
+BUILT = {
+    "qwen-1.5b-d8": ("qwen-1.5b", dict(
+        num_layers=8, d_model=1536, num_heads=12, num_kv_heads=2,
+        head_dim=128, d_ff=8960, vocab_size=151_936, norm_eps=1e-6,
+        rope_theta=10_000.0, tie_embeddings=True)),
+    "phi3-medium-14b-d1": ("phi3-medium-14b", dict(
+        num_layers=1, d_model=5120, num_heads=40, num_kv_heads=10,
+        head_dim=128, d_ff=17920, vocab_size=32_064, norm_eps=1e-5,
+        rope_theta=10_000.0, tie_embeddings=False)),
+    "qwen-1.5b-d12-fsdp4": ("qwen-1.5b", dict(
+        num_layers=12, d_model=1536, num_heads=12, num_kv_heads=2,
+        head_dim=128, d_ff=8960, vocab_size=151_936, norm_eps=1e-6,
+        rope_theta=10_000.0, tie_embeddings=True)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(BUILT))
+def test_each_configuration_builds_the_model_it_built(name):
+    conf = json.loads((CONFIGS / f"{name}.json").read_text())
+    registry, sizes = BUILT[name]
+    assert "program" not in conf
+    assert C.model_config(conf) == dataclasses.replace(get_config(registry),
+                                                       **sizes)
+
+
+@pytest.mark.parametrize("workload,config", [
+    ("qwen1.5b-sft-longalign-1chip", "qwen-1.5b-d8"),
+    ("phi3m-sft-longalign-1chip", "phi3-medium-14b-d1")])
+def test_each_cell_counts_the_model_flops_by_hand(workload, config):
+    """``Context.model_flops`` of a loaded cell is its reference module's
+    count: the hand count above, step by step."""
+    cell = C.load_cell(workload)
+    assert cell.ref is flops
+    r = run(config)
+    steps = [C.StepRecord(1, [100], 0.0, 1.0),
+             C.StepRecord(2, [3, 4], 0.0, 1.0)]
+    ctx = C.Context(run=cell.config["run"], chips=1, peaks=None, setup_s=1.0,
+                    window_s=1.0, steps=steps, S=1024, hbm_peak_bytes=0,
+                    trace=None, step_flops=cell.ref.step_flops)
+    d, f, V = r["hidden_size"], r["intermediate_size"], r["vocab_size"]
+    qd = r["num_attention_heads"] * 128
+    kvd = r["num_key_value_heads"] * 128
+    params = (r["num_hidden_layers"] * (2 * d * qd + 2 * d * kvd + 3 * d * f)
+              + d * V)
+    attn = r["num_hidden_layers"] * 12 * 128 * r["num_attention_heads"]
+    assert ctx.model_flops() == (6 * params * 107
+                                 + attn * (5050 + 6 + 10))

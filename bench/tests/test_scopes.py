@@ -1,17 +1,25 @@
-"""The reduction by scope on a small trace with known answers, the cut
-``bench/trace_scopes.py --keep`` writes, and the program's padding
-counters against the benchmark's ``pad_token_share``."""
+"""The reduction by scope on a small trace with known answers and on a
+trace recorded on the chip, the cut ``bench/run.py --keep`` writes, and
+the program's padding counters against the benchmark's
+``pad_token_share``."""
 import collections
 import json
 import os
 import re
 import types
+from pathlib import Path
 
 import numpy as np
 import pytest
 
-from bench import trace_scopes
 from bench.harness import scopes
+
+DATA = Path(__file__).parent / "data"
+# the readers of the scope reduction (``Context.scopes``)
+SCOPE_METRICS = ("forward_device_ms", "recompute_device_ms",
+                 "backward_device_ms", "optimizer_device_ms",
+                 "loss_head_device_ms", "unscoped_device_share",
+                 "host_to_device_ms")
 
 
 FWD, BWD = "jit(step)/jvp()", "jit(step)/transpose(jvp())"
@@ -123,7 +131,7 @@ def synthetic():
     mods = [(0, 100, "jit_step(11)"), (150, 250, "jit_step(12)")]
     host = [(0, 300, "bench.window"), (100, 104, "data.to_device"),
             (260, 263, "data.to_device"), (305, 310, "data.to_device")]
-    text = trace_scopes.xspace_text(
+    text = scopes.xspace_text(
         [("/device:TPU:0", {"XLA Ops": ops, "XLA Modules": mods}),
          ("/host:CPU", {"python": host})], 0)
     from jax.profiler import ProfileData
@@ -158,12 +166,12 @@ def test_unmatched_steps_count_unscoped():
 
 
 def test_keep_cuts_whole_steps(tmp_path):
-    """``trace_scopes.keep`` writes the first steps as a trace and the
+    """``scopes.keep`` writes the first steps as a trace and the
     instructions they ran; the cut reduces to those steps alone."""
     from jax.profiler import ProfileData
     programs = {m: scopes.op_names(t) for m, t in HLO.items()}
-    trace_scopes.keep(synthetic(), programs, [1, 2], "jit_step", tmp_path,
-                      "cell", steps=1)
+    scopes.keep(synthetic(), programs, [1, 2], "jit_step", tmp_path, "cell",
+                steps=1)
     kept = json.loads((tmp_path / "cell.scopes.json").read_text())
     assert kept["order"] == [1] and kept["module"] == "jit_step"
     assert set(kept["programs"]["1"]) == {"fusion.2", "fusion.7",
@@ -182,8 +190,75 @@ def test_keep_cuts_whole_steps(tmp_path):
 def test_readers_off_a_trace_read_nothing():
     from bench.harness import cell as C
     ctx = types.SimpleNamespace(steps=[object()])
-    for name in trace_scopes.SCOPE_METRICS:
+    for name in SCOPE_METRICS:
         assert C.reader(name)(ctx) is None
+
+
+RECORDED = "qwen1.5b-sft-longalign-1chip"  # bench/run.py --keep, on a v5e
+
+
+def recorded():
+    """The recorded trace of the window's first steps and its instruction
+    map: (trace, programs, order, module)."""
+    from jax.profiler import ProfileData
+    kept = json.loads((DATA / f"{RECORDED}.scopes.json").read_text())
+    pd = ProfileData.from_file(str(DATA / f"{RECORDED}.xplane.pb"))
+    return (pd, {int(m): p for m, p in kept["programs"].items()},
+            kept["order"], kept["module"])
+
+
+def context(found, steps):
+    from bench.harness import cell as C
+    from bench.reference import dense_decoder
+    return C.Context(run={}, chips=1, peaks=None, setup_s=1.0, window_s=1.0,
+                     steps=[object()] * steps, S=1024, hbm_peak_bytes=0,
+                     trace=None, step_flops=dense_decoder.step_flops,
+                     scopes=found)
+
+
+def test_scope_split_of_a_recorded_chip_trace():
+    """``Context.scopes`` as a traced run fills it, from a trace of the
+    qwen cell recorded on a TPU v5e: every step placed in its program,
+    the phases adding up to the trace's busy time, every reader a
+    number."""
+    from bench.harness import cell as C, trace
+    pd, programs, order, module = recorded()
+    found = C.scope_split(pd, programs, order, module)
+    assert found["steps_matched"] == len(order) == 3
+    busy = trace.reduce(pd)["busy_s"]
+    assert sum(found["phases_s"].values()) == pytest.approx(found["busy_s"])
+    assert found["busy_s"] == pytest.approx(busy, rel=1e-9)
+    assert all(found["phases_s"][p] > 0 for p in scopes.PHASES)
+    assert found["phases_s"]["unscoped"] < 0.5 * busy
+    assert {"backward/lm_head", "optimizer/adamw", "forward/attention",
+            "recompute/mlp"} <= set(found["scopes_s"])
+    assert len(found["device_scopes"]) == 10
+    ctx = context(found, len(order))
+    got = {name: C.reader(name)(ctx) for name in SCOPE_METRICS}
+    assert all(v is not None and v >= 0 for v in got.values()), got
+    phases = ("forward", "recompute", "backward", "optimizer")
+    assert sum(got[f"{p}_device_ms"] for p in phases) == pytest.approx(
+        1e3 * (busy - found["phases_s"]["unscoped"]) / len(order))
+
+
+def test_scope_split_reports_nothing_on_a_step_count_mismatch():
+    """A step more in the window than module events in the trace, and a
+    trace with no module events at all (recorded before the step program
+    named its passes): no split, the counts logged, and the readers read
+    nothing."""
+    from jax.profiler import ProfileData
+
+    from bench.harness import cell as C
+    pd, programs, order, module = recorded()
+    logs = []
+    assert C.scope_split(pd, programs, order + order[:1], module,
+                         logs.append) is None
+    assert logs == [f"[bench] scopes: 4 steps in the window, [3] {module} "
+                    "module events start in it; 0 steps matched, no split"]
+    old = ProfileData.from_file(str(DATA / "v5e-1chip-qwen.xplane.pb"))
+    assert C.scope_split(old, programs, [2, 2], module, logs.append) is None
+    ctx = context(None, 2)
+    assert all(C.reader(name)(ctx) is None for name in SCOPE_METRICS)
 
 
 def test_padding_counters_match_pad_token_share(tiny_cell):
@@ -213,22 +288,6 @@ def test_padding_counters_match_pad_token_share(tiny_cell):
     assert 0 < real < slots
     assert 100.0 * (1.0 - real / slots) == pytest.approx(share, abs=1e-9)
     assert np.isclose(real, sum(sum(s.lengths) for s in steps))
-
-
-def test_traced_run_keeps_what_the_run_drops(tiny_cell):
-    """``trace_scopes.traced_run`` is ``cell.run`` traced, keeping the
-    window's trace, each program's HLO and the window's steps; the cell's
-    module is as it was after."""
-    import jax
-
-    from bench.harness import cell as C
-    program, view = C.Program, C.trace
-    out, pd, hlo, steps = trace_scopes.traced_run(
-        tiny_cell(), 11, 0.2, jax.devices()[:1], log=lambda s: None)
-    assert (C.Program, C.trace) == (program, view)
-    assert out["correct"] and len(steps) == out["attempted"] > 0
-    assert pd is not None and {r.m for r in steps} <= set(hlo)
-    assert all(scopes.module_name(t) for t in hlo.values())
 
 
 @pytest.fixture(scope="module")
@@ -289,11 +348,11 @@ def matmul_flops(text):
 
 def test_matmul_flops_keep_the_pass_identities(v5e, tiny_cell):
     """The chip's compiled step (qwen layout at small widths, minibatch
-    schedule, both remat levels, M = 2), its matmuls counted by pass: the
-    backward is twice the forward in every block (dX and dW), attention's
-    batched matmuls (convolutions with a padded, dilated window) count
-    what they compute, and the fusions' op_names move no matmul to
-    another pass or block."""
+    schedule, M = 2), its matmuls counted by pass: the backward is twice
+    the forward in every block (dX and dW), the layer checkpoint is the
+    only recompute, attention's batched matmuls (convolutions with a
+    padded, dilated window) count what they compute, and the fusions'
+    op_names move no matmul to another pass or block."""
     from bench.harness import cell as C
 
     H, F, V, S, HEADS, KV, HD = 256, 512, 1024, 256, 4, 2, 64
@@ -317,11 +376,11 @@ def test_matmul_flops_keep_the_pass_identities(v5e, tiny_cell):
         2 * H * HEADS * HD + 2 * H * KV * HD + 2 * S * HEADS * HD)
     for b in ("attention", "mlp", "lm_head"):
         assert own[f"backward/{b}"] == 2 * fwd(b)
-    # two remat levels redo the layers; the inner one drops the MLP's down
-    # projection, whose output no backward reads; the head sits under the
-    # minibatch checkpoint only
-    assert own["recompute/attention"] == 2 * fwd("attention")
-    assert own["recompute/mlp"] == pytest.approx(5 / 3 * fwd("mlp"))
-    assert own["recompute/lm_head"] == fwd("lm_head")
+    # each microbatch's forward runs once; the layer checkpoint redoes
+    # attention whole and the MLP less its down projection, whose output
+    # no backward reads, and nothing of the head
+    assert own["recompute/attention"] == fwd("attention")
+    assert own["recompute/mlp"] == pytest.approx(2 / 3 * fwd("mlp"))
+    assert own["recompute/lm_head"] == 0
     assert {k.split("/")[0] for k in own} == {"forward", "recompute",
                                              "backward"}
