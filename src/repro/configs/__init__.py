@@ -21,6 +21,7 @@ ARCH_IDS = (
     "minitron_8b",
     "gemma3_27b",
     "qwen_1p5b",  # the paper's own evaluation family (DeepSeek-R1-Distill-Qwen)
+    "deepseek_v2_lite",
 )
 
 _ALIASES = {
@@ -35,6 +36,7 @@ _ALIASES = {
     "minitron-8b": "minitron_8b",
     "gemma3-27b": "gemma3_27b",
     "qwen-1.5b": "qwen_1p5b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 

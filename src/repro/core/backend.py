@@ -808,14 +808,17 @@ def build_schedule_grad(schedule: str, *, loss_sum: Callable,
                         prefetch: Optional[Callable] = None,
                         checkpoint_minibatch: bool = False,
                         pipe_stages: int = 1,
-                        pipe_interleave: bool = False):
+                        pipe_interleave: bool = False,
+                        zero_counts=None):
     """The gradient loop for one device's microbatches under a schedule.
 
     Shared by the flat (``core/train_step.py``) and GSPMD
     (``core/gspmd.py``) engines — the loop structure is the paper's
     contribution and must not fork between them.
 
-      loss_sum(params, mb, pxform, prefetch) -> (nll_sum, token_count)
+      loss_sum(params, mb, pxform, prefetch) -> (nll_sum, counts)
+                counts: the token count, or a pytree of counts whose
+                zeros are ``zero_counts`` (default: a float32 scalar)
       gather_all(params_local) -> fully-materialized params
                                   (schedule='minibatch'/'1f1b')
       pxform    per-layer materialization hook ('layer'/'overlap')
@@ -829,9 +832,12 @@ def build_schedule_grad(schedule: str, *, loss_sum: Callable,
                 microbatch forwards/backwards are issued in, and the
                 interleaved (halved-warmup) variant flag
 
-    Returns grad_core(params_local, microbatches) -> (lsum, tok, grads),
-    to be wrapped in shard_map and normalized by the caller.
+    Returns grad_core(params_local, microbatches) -> (lsum, counts,
+    grads), counts summed over the microbatches, to be wrapped in shard_map
+    and normalized by the caller.
     """
+    if zero_counts is None:
+        zero_counts = jnp.float32(0.0)
     if schedule not in SCHEDULES:
         raise ValueError(f"unknown schedule {schedule!r}; one of {SCHEDULES}")
 
@@ -864,7 +870,7 @@ def build_schedule_grad(schedule: str, *, loss_sum: Callable,
             order = instructions_1f1b(M, pipe_stages,
                                       interleave=pipe_interleave)
             lsum = jnp.float32(0.0)
-            tok = jnp.float32(0.0)
+            tok = zero_counts
             grad_full = None
             pending = {}
             for op, j in order:
@@ -873,7 +879,7 @@ def build_schedule_grad(schedule: str, *, loss_sum: Callable,
                     l, vjp_fn, t = jax.vjp(
                         lambda fp: f(fp, mb), full, has_aux=True)
                     lsum = lsum + l
-                    tok = tok + t
+                    tok = jax.tree.map(jnp.add, tok, t)
                     pending[j] = (vjp_fn, l)
                 else:
                     vjp_fn, l = pending.pop(j)
@@ -902,7 +908,7 @@ def build_schedule_grad(schedule: str, *, loss_sum: Callable,
             full, gather_vjp = jax.vjp(gather_all, params_local)
             lsum, tok, grad_full = _accumulate_grads(
                 lambda fp, mb: loss_sum(fp, mb, None, None),
-                full, microbatches)
+                full, microbatches, zero_counts)
             (grads,) = gather_vjp(grad_full)
             return lsum, tok, grads
 
@@ -919,18 +925,19 @@ def build_schedule_grad(schedule: str, *, loss_sum: Callable,
     def grad_core(params_local, microbatches):
         return _accumulate_grads(
             lambda pl, mb: loss_sum(pl, mb, pxform, pf),
-            params_local, microbatches)
+            params_local, microbatches, zero_counts)
 
     return grad_core
 
 
-def _accumulate_grads(loss_fn: Callable, params, microbatches):
+def _accumulate_grads(loss_fn: Callable, params, microbatches, zero_counts):
     """Scan the microbatches: each one's loss and gradient in one
     value_and_grad, the gradients summed in a carry.
 
-      loss_fn(params, mb) -> (nll_sum, token_count)
+      loss_fn(params, mb) -> (nll_sum, counts), counts shaped as
+      ``zero_counts``
 
-    Returns (lsum, tok, grads); zeros for M == 0.
+    Returns (lsum, counts, grads); zeros for M == 0.
     """
     gfun = jax.value_and_grad(loss_fn, has_aux=True)
 
@@ -938,9 +945,9 @@ def _accumulate_grads(loss_fn: Callable, params, microbatches):
         lsum, tok, gacc = carry
         (l, t), g = gfun(params, mb)
         gacc = jax.tree.map(jnp.add, gacc, g)
-        return (lsum + l, tok + t, gacc), None
+        return (lsum + l, jax.tree.map(jnp.add, tok, t), gacc), None
 
     zeros = jax.tree.map(jnp.zeros_like, params)
     (lsum, tok, grads), _ = jax.lax.scan(
-        body, (jnp.float32(0.0), jnp.float32(0.0), zeros), microbatches)
+        body, (jnp.float32(0.0), zero_counts, zeros), microbatches)
     return lsum, tok, grads

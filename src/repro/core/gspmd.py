@@ -120,6 +120,10 @@ def leaf_pspec(name: str, ndim: int, rules: ShardingRules, *,
         return P(da, mo)
     if name == "wo":  # (q_dim, d)
         return P(mo, da)
+    if name == "wkv_a":  # MLA (d, latent + rope): the latent is normed whole
+        return P(da, None)
+    if name == "wkv_b":  # MLA (latent, heads*(nope+v))
+        return P(da, mo)
     if name in ("w_up", "w_gate"):
         if ndim == 3:  # MoE (E, d, f)
             if ep_data_axis is not None:
@@ -377,6 +381,11 @@ def cache_pspecs(cache, rules: ShardingRules, mesh: Mesh, *,
         if name == "enc_out":  # (B, S_enc, d)
             b = None if shard_seq else dp
             return P(b, None, div(x.shape[-1]))
+        if name in ("c", "k_rope"):  # MLA latent cache (stack..., B, T, r)
+            r = x.ndim - 3
+            if shard_seq:
+                return P(*([None] * r), None, dp, None)
+            return P(*([None] * r), dp, None, None)
         if name == "router_counts":  # (stack..., B, k, E)
             r = x.ndim - 3
             b = None if shard_seq else dp
@@ -536,7 +545,7 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, gcfg: GSPMDConfig,
         if ks and ks[0] in ("layers", "enc_layers", "dec_layers",
                             "mamba", "mamba_tail"):
             first = ks.pop(0)
-            if (first == "layers" and ks and ks[0] in ("moe", "dense")
+            if (first == "layers" and ks and ks[0] in ("moe", "dense", "lead")
                     and len(ks) > 1):
                 ks.pop(0)  # moe super-layer block container
         return ks
@@ -618,7 +627,7 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, gcfg: GSPMDConfig,
 
         def candidates(raw):
             out = [raw, _relative_keys(raw)]
-            if len(raw) > 1 and raw[0] in ("dense", "moe"):
+            if len(raw) > 1 and raw[0] in ("dense", "moe", "lead"):
                 # slice-rooted paths keep the super-layer block container
                 # that registration (rooted at the full tree) stripped
                 out.append(raw[1:])
@@ -643,13 +652,17 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, gcfg: GSPMDConfig,
 
         return jax.tree_util.tree_map_with_path(mat, tree)
 
+    # what each microbatch counts beside its loss: its tokens, and for an
+    # expert model the (token, expert) pairs its held experts computed
+    counted = ("tokens",) + (("moe_pairs_held",) if cfg.num_experts else ())
+
     def loss_sum(p, mb, px, prefetch=None):
         val, metrics = T.loss(
             cfg, p, mb, remat=gcfg.remat, block_kv=gcfg.block_kv,
             moe_groups=gcfg.moe_groups, pxform=px, prefetch=prefetch,
             reduction="sum",
         )
-        return val, metrics["tokens"]
+        return val, {k: metrics[k] for k in counted}
 
     # the schedule loop (gather placement) is the shared seam with the flat
     # engine — repro.core.backend.build_schedule_grad — fed this engine's
@@ -667,6 +680,7 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, gcfg: GSPMDConfig,
         checkpoint_minibatch=True,
         pipe_stages=pipe_stages,
         pipe_interleave=gcfg.pipe_interleave,
+        zero_counts={k: jnp.float32(0.0) for k in counted},
     )
 
     def grad_minibatch(params_local, batch_local):
@@ -684,11 +698,11 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, gcfg: GSPMDConfig,
         return _grad_minibatch(params_local, batch_local)
 
     def _grad_minibatch(params_local, batch_local):
-        lsum, tok, grads = grad_core(params_local, batch_local)
+        lsum, counts, grads = grad_core(params_local, batch_local)
 
         lsum = jax.lax.psum(lsum, manual)
-        tok = jax.lax.psum(tok, manual)
-        denom = jnp.maximum(tok, 1.0)
+        counts = jax.lax.psum(counts, manual)
+        denom = jnp.maximum(counts["tokens"], 1.0)
 
         def finalize(g, spec):
             leftover = tuple(a for a in manual
@@ -698,7 +712,7 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, gcfg: GSPMDConfig,
             return g / denom.astype(g.dtype)
 
         grads = jax.tree.map(finalize, grads, manual_pspecs)
-        return grads, {"loss": lsum / denom, "tokens": tok}
+        return grads, {"loss": lsum / denom, **counts}
 
     # batch leaves carrying a sequence dim at position 2 of (M, B, S, ...)
     # — under cp their S dim is sharded over the cp axis (the host

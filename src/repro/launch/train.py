@@ -327,6 +327,11 @@ def main(argv=None):
                 reg.counter("train.tokens").inc(float(metrics["tokens"]))
                 reg.counter("train.samples").inc(
                     float(len(step_data["lengths"])))
+                if "moe_pairs_held" in metrics:
+                    # (token, expert) pairs the held experts computed,
+                    # padding slots included, beside data.token_slots
+                    reg.counter("moe.pairs_held").inc(
+                        float(metrics["moe_pairs_held"]))
                 reg.step(i)
                 if rec is not None:
                     rec.count("comm wire bytes",

@@ -4,8 +4,10 @@ One ``ModelConfig`` describes every architecture family in the assigned pool:
 dense decoder-only transformers (GQA / RoPE / SwiGLU, local:global attention
 patterns, logit soft-capping), MoE variants (top-k routing), Mamba2 SSD,
 Zamba2-style hybrids, encoder-decoder (audio) backbones and early-fusion
-multimodal backbones.  Modality frontends are stubs per the assignment: the
-backbone consumes precomputed frame/patch embeddings.
+multimodal backbones, and DeepSeek-V2-style multi-head latent attention
+(MLA) with YaRN rope, leading dense layers and an expert layer that holds a
+share of its routed experts.  Modality frontends are stubs per the
+assignment: the backbone consumes precomputed frame/patch embeddings.
 """
 from __future__ import annotations
 
@@ -40,14 +42,37 @@ class ModelConfig:
     final_logit_softcap: float = 0.0  # 0 = disabled (gemma2: 30.0)
     qk_norm: bool = False  # gemma3-style
 
+    # multi-head latent attention (DeepSeek-V2): keys and values are
+    # expanded per head from a normed latent of kv_lora_rank; q/k heads
+    # are qk_nope_head_dim + qk_rope_head_dim wide (the rope part of k is
+    # one vector shared by all heads), v heads v_head_dim
+    kv_lora_rank: int = 0  # 0 = grouped-query attention
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
+    # YaRN rope scaling (DeepseekV2YarnRotaryEmbedding); factor 0 = plain rope
+    yarn_factor: float = 0.0
+    yarn_original_max_position: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+
     # MoE
     num_experts: int = 0  # 0 = dense FFN
     experts_per_token: int = 0
     moe_d_ff: int = 0  # 0 -> d_ff
     moe_period: int = 1  # MoE FFN every k-th layer (llama4: 2 — interleaved)
     moe_shared_expert: bool = False  # dense shared expert on MoE layers
+    shared_expert_d_ff: int = 0  # 0 -> d_ff
+    first_dense_layers: int = 0  # dense layers before the MoE stack
     router_aux_coef: float = 0.01
-    moe_capacity_factor: float = 1.25
+    moe_aux_per_sequence: bool = False  # DeepSeek's sequence-wise balance loss
+    moe_renormalize: bool = True  # top-k weights renormalized to sum to 1
+    moe_capacity_factor: float = 1.25  # 0 = dropless
+    experts_held: int = 0  # this device's share: the first experts_held of
+    #                        num_experts (0 = all); the router keeps all
 
     # SSM (Mamba2 / SSD)
     ssm_state_size: int = 0  # 0 = no ssm layers
@@ -83,6 +108,40 @@ class ModelConfig:
     @property
     def resolved_moe_d_ff(self) -> int:
         return self.moe_d_ff or self.d_ff
+
+    @property
+    def resolved_shared_d_ff(self) -> int:
+        return self.shared_expert_d_ff or self.d_ff
+
+    @property
+    def resolved_experts_held(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
+    def moe_grouped(self) -> bool:
+        """The expert layer of ``moe.moe_apply_grouped``: dropless or a held
+        share.  Otherwise the capacity dispatch of ``moe.moe_apply``."""
+        return bool(self.num_experts) and (
+            self.moe_capacity_factor == 0
+            or self.resolved_experts_held != self.num_experts)
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def mla_qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    def attn_params_per_layer(self) -> int:
+        d = self.d_model
+        if self.is_mla:
+            H, r = self.num_heads, self.kv_lora_rank
+            return (d * H * self.mla_qk_head_dim  # wq
+                    + d * (r + self.qk_rope_head_dim) + r  # wkv_a, kv_norm
+                    + r * H * (self.qk_nope_head_dim + self.v_head_dim)
+                    + H * self.v_head_dim * d)  # wo
+        return d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
 
     @property
     def ssm_d_inner(self) -> int:
@@ -125,14 +184,15 @@ class ModelConfig:
             per_layer += 2 * nh + di  # A_log, D, norm
             n += L * per_layer
         else:
-            attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
+            attn = self.attn_params_per_layer()
             if self.num_experts:
-                n_moe = L // self.moe_period
+                n_moe = self.num_moe_layers
                 n_dense = L - n_moe
-                ff_moe = 3 * d * self.resolved_moe_d_ff * self.num_experts
+                ff_moe = (3 * d * self.resolved_moe_d_ff
+                          * self.resolved_experts_held)
                 ff_moe += d * self.num_experts  # router
                 if self.moe_shared_expert:
-                    ff_moe += 3 * d * self.d_ff
+                    ff_moe += 3 * d * self.resolved_shared_d_ff
                 ff = (n_moe * ff_moe + n_dense * 3 * d * self.d_ff) / max(1, L)
             else:
                 ff = 3 * d * self.d_ff
@@ -153,16 +213,23 @@ class ModelConfig:
                 n += L * per_layer * enc_dec_mult
         return int(n)
 
+    @property
+    def num_moe_layers(self) -> int:
+        """Layers with an expert FFN: after ``first_dense_layers``, every
+        ``moe_period``-th."""
+        return (self.num_layers - self.first_dense_layers) // self.moe_period
+
     def num_active_params(self) -> int:
-        """Active params per token (MoE: only routed experts count)."""
+        """Active params per token (MoE: only routed experts count; of a
+        held share, the expected k * held / E experts a token meets
+        here)."""
         if not self.num_experts:
             return self.num_params()
-        d, L = self.d_model, self.num_layers
         total = self.num_params()
-        n_moe = L // self.moe_period
-        ff_all = 3 * d * self.resolved_moe_d_ff * self.num_experts
-        ff_active = 3 * d * self.resolved_moe_d_ff * max(1, self.experts_per_token)
-        return int(total - n_moe * (ff_all - ff_active))
+        expert = 3 * self.d_model * self.resolved_moe_d_ff
+        held = self.resolved_experts_held
+        active = max(1, self.experts_per_token) * held / self.num_experts
+        return int(total - self.num_moe_layers * expert * (held - active))
 
 
 def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
@@ -179,7 +246,15 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
         frontend_tokens=min(cfg.frontend_tokens, 8) if cfg.frontend_tokens else 0,
     )
     if cfg.num_experts:
-        base.update(num_experts=min(4, cfg.num_experts), moe_d_ff=256)
+        base.update(num_experts=min(4, cfg.num_experts), moe_d_ff=256,
+                    experts_per_token=min(cfg.experts_per_token, 2))
+        if cfg.shared_expert_d_ff:
+            base.update(shared_expert_d_ff=512)
+        if cfg.experts_held:
+            base.update(experts_held=min(cfg.experts_held, 2))
+    if cfg.kv_lora_rank:
+        base.update(kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                    v_head_dim=16)
     if cfg.ssm_state_size:
         base.update(ssm_state_size=16, ssm_head_dim=32, ssm_chunk=32)
     if cfg.num_encoder_layers:

@@ -10,6 +10,7 @@ Everything here is written to be usable from three places:
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -170,13 +171,53 @@ def rope_freqs(head_dim: int, theta: float):
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (..., S, H, hd); positions: (..., S)."""
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(cfg, dim: int):
+    """(inverse frequencies (dim/2,), cos/sin factor) of YaRN rope as
+    DeepseekV2YarnRotaryEmbedding computes them: half-dims below the
+    correction range keep theta's frequencies, those above are divided by
+    the factor, and a linear ramp blends the two in between."""
+    theta, factor = cfg.rope_theta, cfg.yarn_factor
+    orig = cfg.yarn_original_max_position
+
+    def corr_dim(rotations):
+        return (dim * math.log(orig / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(corr_dim(cfg.yarn_beta_slow)), dim - 1)
+    extra = rope_freqs(dim, theta)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    inv = extra / factor * ramp + extra * (1.0 - ramp)
+    return inv, (_yarn_mscale(factor, cfg.yarn_mscale)
+                 / _yarn_mscale(factor, cfg.yarn_mscale_all_dim))
+
+
+def mla_softmax_scale(cfg) -> float:
+    """qk_head_dim^-0.5, times mscale(factor, mscale_all_dim)^2 under
+    YaRN (DeepseekV2Attention.softmax_scale)."""
+    scale = cfg.mla_qk_head_dim ** -0.5
+    if cfg.yarn_factor:
+        scale *= _yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return scale
+
+
+def apply_rope(x, positions, theta: float, freqs=None, mscale: float = 1.0):
+    """x: (..., S, H, hd); positions: (..., S).  ``freqs``: the (hd/2,)
+    inverse frequencies (default: plain rope at ``theta``); ``mscale``
+    multiplies cos and sin."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)  # (hd/2,)
+    if freqs is None:
+        freqs = rope_freqs(hd, theta)  # (hd/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, hd/2)
     angles = angles[..., None, :]  # (..., S, 1, hd/2) broadcast over heads
     sin, cos = jnp.sin(angles), jnp.cos(angles)
+    if mscale != 1.0:
+        sin, cos = sin * mscale, cos * mscale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -215,12 +256,13 @@ def blockwise_attention(
 ):
     """Attention without materializing (S, T) scores.
 
-    q: (B, S, H, hd); k, v: (B, T, KH, hd) with H % KH == 0 (GQA).
+    q: (B, S, H, hd); k: (B, T, KH, hd), v: (B, T, KH, hv) with
+    H % KH == 0 (GQA); hv may differ from hd (latent attention).
     Scans over KV blocks carrying the online-softmax state (m, l, acc).
     Memory: O(S * block_kv) per head instead of O(S * T).
     """
     B, S, H, hd = q.shape
-    T, KH = k.shape[1], k.shape[2]
+    T, KH, hv = k.shape[1], k.shape[2], v.shape[-1]
     G = H // KH
     if scale is None:
         scale = hd ** -0.5
@@ -242,7 +284,7 @@ def blockwise_attention(
     # reshape GQA: (B, S, KH, G, hd)
     qg = q.reshape(B, S, KH, G, hd).astype(jnp.float32) * scale
     kb = k.reshape(B, num_blocks, block_kv, KH, hd).astype(jnp.float32)
-    vb = v.reshape(B, num_blocks, block_kv, KH, hd).astype(jnp.float32)
+    vb = v.reshape(B, num_blocks, block_kv, KH, hv).astype(jnp.float32)
     kvp = kv_positions.reshape(B, num_blocks, block_kv)
     kvs = (
         kv_segment_ids.reshape(B, num_blocks, block_kv)
@@ -283,7 +325,7 @@ def blockwise_attention(
 
     m0 = jnp.full((B, S, KH, G), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, S, KH, G), jnp.float32)
-    acc0 = jnp.zeros((B, S, KH, G, hd), jnp.float32)
+    acc0 = jnp.zeros((B, S, KH, G, hv), jnp.float32)
     blks = (
         jnp.moveaxis(kb, 1, 0),
         jnp.moveaxis(vb, 1, 0),
@@ -292,7 +334,7 @@ def blockwise_attention(
     )
     (m, l, acc), _ = jax.lax.scan(step, (m0, l0, acc0), blks)
     out = acc / jnp.maximum(l[..., None], 1e-30)
-    return out.reshape(B, S, H, hd).astype(q.dtype)
+    return out.reshape(B, S, H, hv).astype(q.dtype)
 
 
 def reference_attention(q, k, v, **kw):
@@ -371,33 +413,9 @@ def attn_apply(
     causal = causal and cross_kv is None
     if cache is not None:
         # decode: write new kv into the cache, attend over the whole cache
-        idx = cache_index  # scalar (wave decode) or (B,) vector (continuous)
-        T = cache["k"].shape[1]
-        if getattr(idx, "ndim", 0) == 1:
-            # per-row write index (continuous batching): each slot decodes
-            # at its own position, so the write is a one-hot select per row
-            # and the validity mask is per-row too.  Rows beyond a slot's
-            # cursor hold stale kv from a retired request; the mask zeroes
-            # their attention weight exactly (blockwise softmax underflows
-            # the -1e9 positions to 0.0), so stale contents are inert.
-            if S != 1:
-                raise ValueError(
-                    f"vector cache_index requires single-token decode, "
-                    f"got S={S}")
-            hot = (jnp.arange(T)[None, :] == idx[:, None])[:, :, None, None]
-            k_cache = jnp.where(hot, k.astype(cache["k"].dtype), cache["k"])
-            v_cache = jnp.where(hot, v.astype(cache["v"].dtype), cache["v"])
-            kv_positions = jnp.arange(T)[None, :].repeat(B, 0)
-            kv_positions = jnp.where(kv_positions <= idx[:, None],
-                                     kv_positions, -(10 ** 9))
-        else:
-            k_cache = jax.lax.dynamic_update_slice_in_dim(cache["k"], k.astype(cache["k"].dtype), idx, axis=1)
-            v_cache = jax.lax.dynamic_update_slice_in_dim(cache["v"], v.astype(cache["v"].dtype), idx, axis=1)
-            kv_positions = jnp.arange(T)[None, :].repeat(B, 0)
-            # positions beyond the write index are invalid
-            kv_positions = jnp.where(kv_positions[0] <= idx + S - 1, kv_positions, -(10 ** 9))
-        cache = {"k": k_cache, "v": v_cache}
-        k, v = k_cache, v_cache
+        cache, kv_positions = _cache_write(cache, {"k": k, "v": v},
+                                           cache_index, S)
+        k, v = cache["k"], cache["v"]
         kv_segment_ids = None
 
     if window is None:
@@ -418,6 +436,111 @@ def attn_apply(
     )
     out = shard_act(out, "q_heads")
     out = jnp.einsum("bsq,qd->bsd", out.reshape(B, S, H * hd), p["wo"])
+    return out, cache
+
+
+def _cache_write(cache, new, idx, S):
+    """Write ``new`` ({name: (B, S, ...)}) into the decode cache ({name:
+    (B, T, ...)}) at ``idx``; returns the cache and the (B, T) key
+    positions, -1e9 where nothing is written yet.
+
+    ``idx`` a scalar (wave decode) or a (B,) vector (continuous batching).
+    Per-row write index: each slot decodes at its own position, so the
+    write is a one-hot select per row and the validity mask is per-row
+    too.  Rows beyond a slot's cursor hold stale entries from a retired
+    request; the mask zeroes their attention weight exactly (blockwise
+    softmax underflows the -1e9 positions to 0.0), so stale contents are
+    inert."""
+    B, T = next(iter(cache.values())).shape[:2]
+    kv_positions = jnp.arange(T)[None, :].repeat(B, 0)
+    if getattr(idx, "ndim", 0) == 1:
+        if S != 1:
+            raise ValueError(
+                f"vector cache_index requires single-token decode, "
+                f"got S={S}")
+        hot = jnp.arange(T)[None, :] == idx[:, None]
+        cache = {n: jnp.where(hot.reshape(hot.shape + (1,) * (c.ndim - 2)),
+                              new[n].astype(c.dtype), c)
+                 for n, c in cache.items()}
+        return cache, jnp.where(kv_positions <= idx[:, None], kv_positions,
+                                -(10 ** 9))
+    cache = {n: jax.lax.dynamic_update_slice_in_dim(
+        c, new[n].astype(c.dtype), idx, axis=1) for n, c in cache.items()}
+    # positions beyond the write index are invalid
+    return cache, jnp.where(kv_positions[0] <= idx + S - 1, kv_positions,
+                            -(10 ** 9))
+
+
+# --------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V2), params + apply + cache
+# --------------------------------------------------------------------------
+def mla_params(key, cfg, dtype, prefix_shape=()):
+    d, H, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": dense_init(ks[0], prefix_shape + (d, H * (dn + dr)), dtype),
+        "wkv_a": dense_init(ks[1], prefix_shape + (d, r + dr), dtype),
+        "kv_norm": jnp.zeros(prefix_shape + (r,), dtype),
+        "wkv_b": dense_init(ks[2], prefix_shape + (r, H * (dn + dv)), dtype),
+        "wo": dense_init(ks[3], prefix_shape + (H * dv, d), dtype,
+                         scale=1.0 / max(1, cfg.num_layers) ** 0.5),
+    }
+
+
+def mla_apply(cfg, p, x, *, window=0, positions=None, segment_ids=None,
+              cache=None, cache_index=None, block_kv: int = 512):
+    """Multi-head latent attention without query compression.
+
+    q = x Wq, per head [q_nope (dn) | q_rope (dr)]; [c | k_rope] = x Wkv_a
+    with c the latent (kv_lora_rank), normed, and k_rope one dr-wide key
+    shared by every head; [k_nope | v] = c Wkv_b per head; rope (YaRN
+    where configured) on q_rope and k_rope; o = softmax(q k^T scale) v Wo.
+
+    cache: {"c": (B, T, r), "k_rope": (B, T, dr)} for decode: the latent
+    and the rope key are cached, and the whole cache is expanded per call.
+    Returns (out, updated_cache).
+    """
+    B, S, _ = x.shape
+    H, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
+    if positions is None:
+        positions = jnp.arange(S)[None, :].repeat(B, 0)
+    q = jnp.einsum("bsd,dq->bsq", x, p["wq"]).reshape(B, S, H, -1)
+    q = shard_act(q, "q_heads")
+    with jax.named_scope("latent"):
+        ckr = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"])
+        c = rms_norm(ckr[..., :r], p["kv_norm"], cfg.norm_eps)
+        k_rope = ckr[..., r:]
+    freqs, mscale = (yarn_freqs(cfg, cfg.qk_rope_head_dim) if cfg.yarn_factor
+                     else (None, 1.0))
+    rope = lambda t: apply_rope(t, positions, cfg.rope_theta, freqs, mscale)
+    q = jnp.concatenate([q[..., :dn], rope(q[..., dn:])], axis=-1)
+    k_rope = rope(k_rope[:, :, None, :])[:, :, 0]
+    kv_positions, kv_segment_ids = positions, segment_ids
+    if cache is not None:
+        cache, kv_positions = _cache_write(cache, {"c": c, "k_rope": k_rope},
+                                           cache_index, S)
+        c, k_rope = cache["c"], cache["k_rope"]
+        kv_segment_ids = None
+    T = c.shape[1]
+    with jax.named_scope("expand"):
+        kv = jnp.einsum("btr,rh->bth", c, p["wkv_b"]).reshape(B, T, H, -1)
+    k = jnp.concatenate(
+        [kv[..., :dn],
+         jnp.broadcast_to(k_rope[:, :, None, :], (B, T, H, k_rope.shape[-1]))],
+        axis=-1)
+    k = shard_act(k, "kv_heads")
+    v = shard_act(kv[..., dn:], "kv_heads")
+    attn_fn = _ATTN_IMPL or blockwise_attention
+    out = attn_fn(
+        q, k, v, causal=True, window=window,
+        logit_softcap=cfg.attn_logit_softcap, q_positions=positions,
+        kv_positions=kv_positions, q_segment_ids=segment_ids,
+        kv_segment_ids=kv_segment_ids, block_kv=block_kv,
+        scale=mla_softmax_scale(cfg))
+    out = shard_act(out, "q_heads")
+    out = jnp.einsum("bsq,qd->bsd", out.reshape(B, S, H * dv), p["wo"])
     return out, cache
 
 

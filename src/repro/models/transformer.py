@@ -16,7 +16,10 @@ targets (B,S), loss_mask (B,S) float; family extras: ``encoder_embeds``
 Layer trunks are ``lax.scan`` over stacked layer params (fast compiles at
 40-64 layers).  MoE archs scan over "super-layers" of ``moe_period`` layers
 ((period-1) dense + 1 MoE), so dense and MoE layers can carry different
-parameter structures while the scan stays uniform.
+parameter structures while the scan stays uniform; ``first_dense_layers``
+leading dense layers (DeepSeek-V2) are a stack of their own
+(``layers/lead``), run before that scan.  Attention is grouped-query, or
+multi-head latent attention where the config sets ``kv_lora_rank``.
 
 ``pxform`` is the FSDP hook: a transform applied to parameter subtrees at
 materialization points (per-layer inside the scan bodies, or once at the
@@ -42,11 +45,17 @@ from repro.models.config import ModelConfig
 # ===========================================================================
 # parameter init
 # ===========================================================================
+def _attn_params(key, cfg, dtype, prefix_shape=()):
+    if cfg.is_mla:
+        return L.mla_params(key, cfg, dtype, prefix_shape)
+    return L.attn_params(key, cfg, dtype, prefix_shape)
+
+
 def _dense_block_params(key, cfg, dtype, prefix_shape=()):
     ks = jax.random.split(key, 2)
     return {
         "attn_norm": jnp.zeros(prefix_shape + (cfg.d_model,), dtype),
-        "attn": L.attn_params(ks[0], cfg, dtype, prefix_shape),
+        "attn": _attn_params(ks[0], cfg, dtype, prefix_shape),
         "mlp_norm": jnp.zeros(prefix_shape + (cfg.d_model,), dtype),
         "mlp": L.mlp_params(ks[1], cfg, dtype, prefix_shape),
     }
@@ -56,12 +65,13 @@ def _moe_block_params(key, cfg, dtype, prefix_shape=()):
     ks = jax.random.split(key, 3)
     p = {
         "attn_norm": jnp.zeros(prefix_shape + (cfg.d_model,), dtype),
-        "attn": L.attn_params(ks[0], cfg, dtype, prefix_shape),
+        "attn": _attn_params(ks[0], cfg, dtype, prefix_shape),
         "mlp_norm": jnp.zeros(prefix_shape + (cfg.d_model,), dtype),
         "moe": moe_mod.moe_params(ks[1], cfg, dtype, prefix_shape),
     }
     if cfg.moe_shared_expert:
-        p["shared_mlp"] = L.mlp_params(ks[2], cfg, dtype, prefix_shape)
+        p["shared_mlp"] = L.mlp_params(ks[2], cfg, dtype, prefix_shape,
+                                       d_ff=cfg.resolved_shared_d_ff)
     return p
 
 
@@ -107,10 +117,13 @@ def init_params(cfg: ModelConfig, key, dtype=jnp.float32):
         params["dec_layers"] = _encdec_dec_params(keys[3], cfg, dtype, (cfg.num_layers,))
     elif cfg.num_experts:
         P = cfg.moe_period
-        n_super = cfg.num_layers // P
+        n_super = cfg.num_moe_layers
         blocks = {"moe": _moe_block_params(keys[2], cfg, dtype, (n_super,))}
         if P > 1:
             blocks["dense"] = _dense_block_params(keys[3], cfg, dtype, (n_super, P - 1))
+        if cfg.first_dense_layers:
+            blocks["lead"] = _dense_block_params(
+                keys[4], cfg, dtype, (cfg.first_dense_layers,))
         params["layers"] = blocks
     else:  # dense / vlm
         params["layers"] = _dense_block_params(keys[2], cfg, dtype, (cfg.num_layers,))
@@ -120,11 +133,18 @@ def init_params(cfg: ModelConfig, key, dtype=jnp.float32):
 # ===========================================================================
 # layer application
 # ===========================================================================
+def _attention(cfg, p, h, **kw):
+    """The layer's self-attention: latent (MLA) or grouped-query."""
+    if cfg.is_mla:
+        return L.mla_apply(cfg, p, h, **kw)
+    return L.attn_apply(cfg, p, h, **kw)
+
+
 def _apply_dense_block(cfg, lp, x, *, window, positions, segment_ids, cache,
                        cache_index, block_kv, causal=True):
     with jax.named_scope("attention"):
         h = L.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        a, cache = L.attn_apply(
+        a, cache = _attention(
             cfg, lp["attn"], h, window=window, positions=positions,
             segment_ids=segment_ids, cache=cache, cache_index=cache_index,
             block_kv=block_kv,
@@ -138,6 +158,9 @@ def _apply_dense_block(cfg, lp, x, *, window, positions, segment_ids, cache,
 
 def _apply_moe_block(cfg, lp, x, *, window, positions, segment_ids, cache,
                      cache_index, block_kv, moe_groups):
+    """Returns (x, cache, aux, pairs): ``pairs`` the (token, expert) pairs
+    the held experts computed (``moe_apply_grouped``; 0 for the capacity
+    dispatch)."""
     # the decode cache carries the router's per-expert usage tally next to
     # the KV buffers: capacity drops depend on how many earlier tokens hit
     # each expert, state an incremental decode can't otherwise see
@@ -148,15 +171,19 @@ def _apply_moe_block(cfg, lp, x, *, window, positions, segment_ids, cache,
         attn_cache = {k: v for k, v in cache.items() if k != "router_counts"}
     with jax.named_scope("attention"):
         h = L.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        a, attn_cache = L.attn_apply(
+        a, attn_cache = _attention(
             cfg, lp["attn"], h, window=window, positions=positions,
             segment_ids=segment_ids, cache=attn_cache,
             cache_index=cache_index, block_kv=block_kv,
         )
     x = x + a
+    pairs = jnp.float32(0.0)
     with jax.named_scope("moe"):
         h = L.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        if router_counts is not None:
+        if cfg.moe_grouped:
+            ffn, aux, pairs = moe_mod.moe_apply_grouped(
+                cfg, lp["moe"], h, segment_ids=segment_ids)
+        elif router_counts is not None:
             ffn, aux, router_counts = moe_mod.moe_apply(
                 cfg, lp["moe"], h, groups=moe_groups,
                 router_counts=router_counts,
@@ -172,7 +199,7 @@ def _apply_moe_block(cfg, lp, x, *, window, positions, segment_ids, cache,
     if router_counts is not None:
         new_cache = dict(attn_cache)
         new_cache["router_counts"] = router_counts
-    return x, new_cache, aux
+    return x, new_cache, aux, pairs
 
 
 def _apply_mamba_block(cfg, lp, x, *, cache):
@@ -238,10 +265,20 @@ def _prefetch_scan(*args, **kwargs):
 def _forward_dense(cfg, params, batch, caches, cache_index, remat, block_kv,
                    pxform, prefetch=None):
     x = _embed(cfg, params, batch)
-    positions = batch.get("positions")
-    segment_ids = batch.get("segment_ids")
     sw = _static_window(cfg)  # hoisted when uniform across layers
     windows = None if sw is not None else _window_schedule(cfg)
+    x, new_caches = _dense_trunk(
+        cfg, params["layers"], x, batch, caches, cache_index, remat,
+        block_kv, pxform, prefetch, sw, windows)
+    return x, jnp.float32(0.0), new_caches
+
+
+def _dense_trunk(cfg, stack, x, batch, caches, cache_index, remat, block_kv,
+                 pxform, prefetch, sw, windows):
+    """A stack of dense blocks, scanned; ``sw`` the static window of every
+    layer, else ``windows`` the per-layer schedule.  Returns (x, caches)."""
+    positions = batch.get("positions")
+    segment_ids = batch.get("segment_ids")
 
     if prefetch is not None:
         def pbody(x, scanned):
@@ -256,9 +293,9 @@ def _forward_dense(cfg, params, batch, caches, cache_index, remat, block_kv,
             )
 
         extras = () if sw is not None else (windows,)
-        x, _ = _prefetch_scan(pbody, x, params["layers"], extras,
+        x, _ = _prefetch_scan(pbody, x, stack, extras,
                               prefetch=prefetch, remat=remat)
-        return x, jnp.float32(0.0), None
+        return x, None
 
     def body(x, scanned):
         lp, *rest = scanned
@@ -273,26 +310,36 @@ def _forward_dense(cfg, params, batch, caches, cache_index, remat, block_kv,
 
     if remat:
         body = jax.checkpoint(body)
-    xs = (params["layers"],)
+    xs = (stack,)
     if sw is None:
         xs += (windows,)
     if caches is not None:
         xs += (caches,)
-    x, new_caches = jax.lax.scan(body, x, xs)
-    return x, jnp.float32(0.0), new_caches
+    return jax.lax.scan(body, x, xs)
 
 
 def _forward_moe(cfg, params, batch, caches, cache_index, remat, block_kv,
                  moe_groups, pxform, prefetch=None):
+    """Returns (x, aux, caches, pairs held)."""
     x = _embed(cfg, params, batch)
     positions = batch.get("positions")
     segment_ids = batch.get("segment_ids")
     P = cfg.moe_period
-    blocks = params["layers"]
+    blocks = dict(params["layers"])
+    lead = blocks.pop("lead", None)
+    lead_cache = None
+    if caches is not None and "lead" in caches:
+        caches = dict(caches)
+        lead_cache = caches.pop("lead")
+    if lead is not None:
+        x, lead_cache = _dense_trunk(
+            cfg, lead, x, batch, lead_cache, cache_index, remat, block_kv,
+            pxform, prefetch, 0, None)
+    zero = jnp.float32(0.0)
 
     if prefetch is not None:
         def pbody(carry, scanned):
-            x, aux = carry
+            x, aux, pairs = carry
             (lp,) = scanned  # whole super-layer slice, pre-materialized
             if P > 1:
                 for j in range(P - 1):
@@ -302,21 +349,21 @@ def _forward_moe(cfg, params, batch, caches, cache_index, remat, block_kv,
                         segment_ids=segment_ids, cache=None,
                         cache_index=cache_index, block_kv=block_kv,
                     )
-            x, _, aux_l = _apply_moe_block(
+            x, _, aux_l, pairs_l = _apply_moe_block(
                 cfg, lp["moe"], x, window=0, positions=positions,
                 segment_ids=segment_ids, cache=None,
                 cache_index=cache_index, block_kv=block_kv,
                 moe_groups=moe_groups,
             )
-            return (x, aux + aux_l), None
+            return (x, aux + aux_l, pairs + pairs_l), None
 
-        (x, aux), _ = _prefetch_scan(
-            pbody, (x, jnp.float32(0.0)), blocks, (),
+        (x, aux, pairs), _ = _prefetch_scan(
+            pbody, (x, zero, zero), blocks, (),
             prefetch=prefetch, remat=remat)
-        return x, aux, None
+        return x, aux, None, pairs
 
     def body(carry, scanned):
-        x, aux = carry
+        x, aux, pairs = carry
         if caches is None:
             lp, cache = scanned, None
         else:
@@ -338,20 +385,22 @@ def _forward_moe(cfg, params, batch, caches, cache_index, remat, block_kv,
                 dense_caches.append(c)
             if dense_caches[0] is not None:
                 new_cache["dense"] = jax.tree.map(lambda *a: jnp.stack(a), *dense_caches)
-        x, moe_cache, aux_l = _apply_moe_block(
+        x, moe_cache, aux_l, pairs_l = _apply_moe_block(
             cfg, pxform(lp["moe"]), x, window=0, positions=positions,
             segment_ids=segment_ids, cache=cache["moe"] if cache is not None else None,
             cache_index=cache_index, block_kv=block_kv, moe_groups=moe_groups,
         )
         if moe_cache is not None:
             new_cache["moe"] = moe_cache
-        return (x, aux + aux_l), new_cache
+        return (x, aux + aux_l, pairs + pairs_l), new_cache
 
     if remat:
         body = jax.checkpoint(body)
     xs = blocks if caches is None else (blocks, caches)
-    (x, aux), new_caches = jax.lax.scan(body, (x, jnp.float32(0.0)), xs)
-    return x, aux, new_caches
+    (x, aux, pairs), new_caches = jax.lax.scan(body, (x, zero, zero), xs)
+    if lead_cache is not None:
+        new_caches = dict(new_caches, lead=lead_cache)
+    return x, aux, new_caches, pairs
 
 
 def _forward_ssm(cfg, params, batch, caches, remat, pxform, prefetch=None):
@@ -577,6 +626,16 @@ def apply(cfg: ModelConfig, params, batch, *, caches=None, cache_index=None,
     layer trunks to the double-buffered ``odc.prefetch_scan``
     (schedule='overlap'); training only, ignored on cached (serve) paths.
     """
+    logits, aux, new_caches, _ = _apply(
+        cfg, params, batch, caches=caches, cache_index=cache_index,
+        remat=remat, block_kv=block_kv, moe_groups=moe_groups,
+        pxform=pxform, prefetch=prefetch, last_only=last_only)
+    return logits, aux, new_caches
+
+
+def _apply(cfg, params, batch, *, caches, cache_index, remat, block_kv,
+           moe_groups, pxform, prefetch, last_only):
+    """:func:`apply`, plus the expert pairs computed here (MoE family)."""
     if pxform is None:
         pxform = lambda t: t
         prefetch = None  # prefetch is an FSDP mode; needs pxform for the
@@ -588,6 +647,7 @@ def apply(cfg: ModelConfig, params, batch, *, caches=None, cache_index=None,
     if caches is not None:
         prefetch = None
     fam = cfg.family
+    pairs = None
     if fam == "ssm":
         x, aux, new_caches = _forward_ssm(cfg, params, batch, caches, remat, pxform, prefetch)
     elif fam == "hybrid":
@@ -595,12 +655,12 @@ def apply(cfg: ModelConfig, params, batch, *, caches=None, cache_index=None,
     elif fam == "audio":
         x, aux, new_caches = _forward_audio(cfg, params, batch, caches, cache_index, remat, block_kv, pxform, prefetch)
     elif cfg.num_experts:
-        x, aux, new_caches = _forward_moe(cfg, params, batch, caches, cache_index, remat, block_kv, moe_groups, pxform, prefetch)
+        x, aux, new_caches, pairs = _forward_moe(cfg, params, batch, caches, cache_index, remat, block_kv, moe_groups, pxform, prefetch)
     else:
         x, aux, new_caches = _forward_dense(cfg, params, batch, caches, cache_index, remat, block_kv, pxform, prefetch)
     if last_only:
         x = x[:, -1:]
-    return _logits(cfg, params, x), aux, new_caches
+    return _logits(cfg, params, x), aux, new_caches, pairs
 
 
 def loss(cfg: ModelConfig, params, batch, *, remat: bool = False,
@@ -610,11 +670,17 @@ def loss(cfg: ModelConfig, params, batch, *, remat: bool = False,
     advantage weighting by passing signed weights).
 
     reduction='sum' returns the un-normalized nll sum (used by the FSDP
-    engines to accumulate across microbatches before global normalization)."""
-    logits, aux, _ = apply(
-        cfg, params, batch, remat=remat, block_kv=block_kv, moe_groups=moe_groups,
-        pxform=pxform, prefetch=prefetch,
-    )
+    engines to accumulate across microbatches before global normalization).
+
+    The router's balance loss is a per-token mean, added as such, or with
+    ``moe_aux_per_sequence`` a sum over samples (``moe.
+    sequence_balance_loss``), added to the summed cross-entropy.  MoE
+    configs add ``moe_pairs_held`` to the metrics: the (token, expert)
+    pairs the held experts computed, summed over layers."""
+    logits, aux, _, pairs = _apply(
+        cfg, params, batch, caches=None, cache_index=None, remat=remat,
+        block_kv=block_kv, moe_groups=moe_groups, pxform=pxform,
+        prefetch=prefetch, last_only=False)
     targets = batch["targets"]
     mask = batch.get("loss_mask")
     if mask is None:
@@ -626,13 +692,16 @@ def loss(cfg: ModelConfig, params, batch, *, remat: bool = False,
                                         axis=-1)[..., 0]
         nll = (logz - tgt_logit) * mask
     tokens = jnp.sum(jnp.abs(mask))
+    extra = {} if pairs is None else {"moe_pairs_held": pairs}
     if reduction == "sum":
-        total = jnp.sum(nll) + aux * jnp.maximum(tokens, 1.0)
-        return total, {"ce_sum": jnp.sum(nll), "aux": aux, "tokens": tokens}
+        total = jnp.sum(nll) + (aux if cfg.moe_aux_per_sequence
+                                else aux * jnp.maximum(tokens, 1.0))
+        return total, {"ce_sum": jnp.sum(nll), "aux": aux, "tokens": tokens,
+                       **extra}
     denom = jnp.maximum(tokens, 1.0)
     ce = jnp.sum(nll) / denom
-    total = ce + aux
-    return total, {"ce": ce, "aux": aux, "tokens": tokens}
+    total = ce + (aux / denom if cfg.moe_aux_per_sequence else aux)
+    return total, {"ce": ce, "aux": aux, "tokens": tokens, **extra}
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.float32,
@@ -642,6 +711,13 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.float32,
     hd, KH = cfg.resolved_head_dim, cfg.num_kv_heads
 
     def attn_cache(prefix=()):
+        if cfg.is_mla:  # the latent and the shared rope key, per token
+            return {
+                "c": jnp.zeros(prefix + (batch, max_len, cfg.kv_lora_rank),
+                               dtype),
+                "k_rope": jnp.zeros(
+                    prefix + (batch, max_len, cfg.qk_rope_head_dim), dtype),
+            }
         return {
             "k": jnp.zeros(prefix + (batch, max_len, KH, hd), dtype),
             "v": jnp.zeros(prefix + (batch, max_len, KH, hd), dtype),
@@ -674,15 +750,18 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.float32,
         return {"self": attn_cache((cfg.num_layers,)), "enc_out": enc_out}
     if cfg.num_experts:
         P = cfg.moe_period
-        n_super = cfg.num_layers // P
+        n_super = cfg.num_moe_layers
         moe_c = attn_cache((n_super,))
-        # router usage tally: makes capacity-drop decisions causally
-        # consistent between prefill and decode (see moe.moe_apply)
-        moe_c["router_counts"] = jnp.zeros(
-            (n_super, batch, cfg.experts_per_token, cfg.num_experts),
-            jnp.int32)
+        if not cfg.moe_grouped:
+            # router usage tally: makes capacity-drop decisions causally
+            # consistent between prefill and decode (see moe.moe_apply)
+            moe_c["router_counts"] = jnp.zeros(
+                (n_super, batch, cfg.experts_per_token, cfg.num_experts),
+                jnp.int32)
         c = {"moe": moe_c}
         if P > 1:
             c["dense"] = attn_cache((n_super, P - 1))
+        if cfg.first_dense_layers:
+            c["lead"] = attn_cache((cfg.first_dense_layers,))
         return c
     return attn_cache((cfg.num_layers,))

@@ -92,6 +92,7 @@ def test_full_config_is_exact(arch):
         "minitron_8b": (32, 4096, 32, 8, 16384, 256_000),
         "gemma3_27b": (62, 5376, 32, 16, 21504, 262_144),
         "qwen_1p5b": (28, 1536, 12, 2, 8960, 151_936),
+        "deepseek_v2_lite": (27, 2048, 16, 16, 10944, 102_400),
     }[arch]
     got = (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
            cfg.d_ff, cfg.vocab_size)
@@ -111,6 +112,7 @@ def test_param_counts_in_band():
         "minitron_8b": (7e9, 10e9),
         "gemma3_27b": (23e9, 31e9),
         "qwen_1p5b": (1.2e9, 2.1e9),
+        "deepseek_v2_lite": (14e9, 17e9),  # published 15.7 B
     }
     for arch, (lo, hi) in bands.items():
         n = get_config(arch).num_params()

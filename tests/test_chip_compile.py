@@ -168,3 +168,42 @@ def test_minibatch_step_recomputes_only_the_layers(step_hlo):
     assert own["recompute/lm_head"] == 0
     for b in ("attention", "mlp", "lm_head"):
         assert own[f"backward/{b}"] == 2 * own[f"forward/{b}"]
+
+
+def test_expert_layer_kernels_compile_under_their_scope(one_chip,
+                                                        monkeypatch):
+    """DeepSeek-V2-Lite's held expert layer at real widths (8 held of 64,
+    d 2,048, width 1,408, 1,024 tokens, float32), forward and backward:
+    its grouped matmuls compile as the megablox kernels within the scoped
+    VMEM, and each keeps the ``moe`` block on its ``op_name``, which the
+    benchmark's trace reduction reads (XLA's kernel for
+    ``jax.lax.ragged_dot`` drops it)."""
+    import dataclasses
+
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+    from bench.harness import scopes
+    from repro.configs import get_config
+    from repro.models import moe
+
+    monkeypatch.setattr(moe, "_kernel", lambda: False)
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite"), experts_held=8)
+    p = jax.eval_shape(lambda: moe.moe_params(jax.random.PRNGKey(0), cfg,
+                                              jnp.float32))
+    x = jax.ShapeDtypeStruct((1, 1024, 2048), jnp.float32,
+                             sharding=one_chip)
+    p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                    sharding=one_chip), p)
+
+    def loss(p, x):
+        with jax.named_scope("moe"):
+            return jnp.sum(moe.moe_apply_grouped(cfg, p, x)[0])
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(p, x).compile(
+        ).as_text()
+    assert "ragged-dot" not in hlo
+    names = scopes.op_names(hlo)
+    kernels = [n for n in names if re.match(r"t?gmm(\.\d+)?$", n)]
+    # up, gate, down: forward, x's gradient, the weights' gradient
+    assert len(kernels) == 9, sorted(names)
+    for n in kernels:
+        assert scopes.label(names[n]).endswith("/moe"), names[n]
