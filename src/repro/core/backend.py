@@ -809,7 +809,8 @@ def build_schedule_grad(schedule: str, *, loss_sum: Callable,
                         checkpoint_minibatch: bool = False,
                         pipe_stages: int = 1,
                         pipe_interleave: bool = False,
-                        zero_counts=None):
+                        zero_counts=None,
+                        fold_mask: Optional[Callable] = None):
     """The gradient loop for one device's microbatches under a schedule.
 
     Shared by the flat (``core/train_step.py``) and GSPMD
@@ -831,6 +832,12 @@ def build_schedule_grad(schedule: str, *, loss_sum: Callable,
                 depth whose stage-0 ``instructions_1f1b`` order the
                 microbatch forwards/backwards are issued in, and the
                 interleaved (halved-warmup) variant flag
+      fold_mask schedule='minibatch' only: fold_mask(params) -> a bool
+                pytree shaped as params, True on the leaves whose gradient
+                ``loss_sum(..., grad_acc=acc)`` returns with the running
+                microbatch sum ``acc`` already added (folded into the
+                model's backward); None sums every leaf in the loop, as
+                does a single microbatch
 
     Returns grad_core(params_local, microbatches) -> (lsum, counts,
     grads), counts summed over the microbatches, to be wrapped in shard_map
@@ -905,10 +912,22 @@ def build_schedule_grad(schedule: str, *, loss_sum: Callable,
             # back through the gather emits exactly one scatter-accumulate
             # per parameter at the minibatch end (paper Fig. 2).  Each
             # microbatch's forward runs once, right before its backward.
+            # With fold_mask the sum is folded into the model's backward
+            # where it has two or more microbatches to add (a single one's
+            # gradient is the sum: the compiler drops the add to zeros).
             full, gather_vjp = jax.vjp(gather_all, params_local)
+            M = jax.tree_util.tree_leaves(microbatches)[0].shape[0]
+            folded = fold_mask(full) if fold_mask and M > 1 else None
+            if folded is not None and any(jax.tree.leaves(folded)):
+                def loss_fn(fp, mb, acc):
+                    return loss_sum(fp, mb, None, None, grad_acc=acc)
+            else:
+                folded = None
+
+                def loss_fn(fp, mb):
+                    return loss_sum(fp, mb, None, None)
             lsum, tok, grad_full = _accumulate_grads(
-                lambda fp, mb: loss_sum(fp, mb, None, None),
-                full, microbatches, zero_counts)
+                loss_fn, full, microbatches, zero_counts, folded)
             (grads,) = gather_vjp(grad_full)
             return lsum, tok, grads
 
@@ -930,21 +949,41 @@ def build_schedule_grad(schedule: str, *, loss_sum: Callable,
     return grad_core
 
 
-def _accumulate_grads(loss_fn: Callable, params, microbatches, zero_counts):
+def _accumulate_grads(loss_fn: Callable, params, microbatches, zero_counts,
+                      folded=None):
     """Scan the microbatches: each one's loss and gradient in one
     value_and_grad, the gradients summed in a carry.
 
       loss_fn(params, mb) -> (nll_sum, counts), counts shaped as
-      ``zero_counts``
+      ``zero_counts``; with ``folded``, loss_fn(params, mb, acc)
+      folded    None, or a bool pytree shaped as params: True where the
+                gradient comes back with the carried sum ``acc`` already
+                added, which then becomes the carry as it is
 
-    Returns (lsum, counts, grads); zeros for M == 0.
+    Records, in the active metrics registry, the gauges
+    ``grad.folded_params`` and ``grad.outer_summed_params``: the elements
+    of the sum (per device) folded into the backward, and those added
+    here.  Returns (lsum, counts, grads); zeros for M == 0.
     """
     gfun = jax.value_and_grad(loss_fn, has_aux=True)
+    reg = obs_metrics.active()
+    if reg is not None:
+        leaves = jax.tree.leaves(params)
+        n = 0 if folded is None else sum(
+            x.size for f, x in zip(jax.tree.leaves(folded), leaves) if f)
+        reg.gauge("grad.folded_params").set(float(n))
+        reg.gauge("grad.outer_summed_params").set(
+            float(sum(x.size for x in leaves) - n))
 
     def body(carry, mb):
         lsum, tok, gacc = carry
-        (l, t), g = gfun(params, mb)
-        gacc = jax.tree.map(jnp.add, gacc, g)
+        if folded is None:
+            (l, t), g = gfun(params, mb)
+            gacc = jax.tree.map(jnp.add, gacc, g)
+        else:
+            (l, t), g = gfun(params, mb, gacc)
+            gacc = jax.tree.map(lambda f, a, b: b if f else a + b,
+                                folded, gacc, g)
         return (lsum + l, jax.tree.map(jnp.add, tok, t), gacc), None
 
     zeros = jax.tree.map(jnp.zeros_like, params)

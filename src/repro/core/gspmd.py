@@ -656,11 +656,11 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, gcfg: GSPMDConfig,
     # expert model the (token, expert) pairs its held experts computed
     counted = ("tokens",) + (("moe_pairs_held",) if cfg.num_experts else ())
 
-    def loss_sum(p, mb, px, prefetch=None):
+    def loss_sum(p, mb, px, prefetch=None, grad_acc=None):
         val, metrics = T.loss(
             cfg, p, mb, remat=gcfg.remat, block_kv=gcfg.block_kv,
             moe_groups=gcfg.moe_groups, pxform=px, prefetch=prefetch,
-            reduction="sum",
+            reduction="sum", grad_acc=grad_acc,
         )
         return val, {k: metrics[k] for k in counted}
 
@@ -670,7 +670,8 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, gcfg: GSPMDConfig,
     # forward once, right before its backward, so only the model's own
     # per-layer remat recomputes; the checkpoint flag remats the forwards
     # that 1f1b keeps in flight (full-model gradient residency is the ODC
-    # trade, not activations)
+    # trade, not activations).  Its gradient sum over microbatches is folded
+    # into the layer scans' backward (``T.grad_fold_mask``)
     grad_core = B.build_schedule_grad(
         schedule,
         loss_sum=loss_sum,
@@ -681,6 +682,8 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, gcfg: GSPMDConfig,
         pipe_stages=pipe_stages,
         pipe_interleave=gcfg.pipe_interleave,
         zero_counts={k: jnp.float32(0.0) for k in counted},
+        fold_mask=((lambda p: T.grad_fold_mask(cfg, p)) if gcfg.remat
+                   else None),
     )
 
     def grad_minibatch(params_local, batch_local):

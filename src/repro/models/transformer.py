@@ -27,6 +27,14 @@ top for global leaves).  ``prefetch`` (training only) switches the layer
 scans to the software-pipelined ``odc.prefetch_scan``: the transform is
 applied to layer l+1's slice during layer l's compute — the
 ``schedule='overlap'`` double-buffered gather/scatter discipline.
+
+``grad_acc`` (training, ``schedule='minibatch'``) folds the running
+microbatch gradient sum into the backward of the layer scans
+(:func:`_fold_scan`): each layer adds its weight gradient into its slice of
+the sum, in place, in the scan iteration that computes it, where XLA fuses
+the add into the weight-gradient matmul.  Without it the scan's transpose
+writes a fresh zero-filled gradient stack, and the sum reads that stack and
+the running sum in a pass of its own after the scan.
 """
 from __future__ import annotations
 
@@ -255,6 +263,76 @@ def _embed(cfg, params, batch):
     return x
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _fold_scan(step, carry, stack, acc, xs):
+    """``jax.lax.scan`` of ``step(carry, layer slice, xs slice) -> carry``
+    over ``stack``, checkpointed by layer; its backward adds each layer's
+    parameter cotangent into that layer's slice of ``acc``, a running
+    gradient sum shaped as ``stack``, in place.  The cotangent of
+    ``stack`` leaves as ``acc`` plus the gradient; ``acc`` gets none."""
+    return jax.lax.scan(lambda c, s: (step(c, *s), None), carry,
+                        (stack, xs))[0]
+
+
+def _fold_scan_fwd(step, carry, stack, acc, xs):
+    # each layer's input is the residual, as the checkpoint keeps it
+    out, carries = jax.lax.scan(lambda c, s: (step(c, *s), c), carry,
+                                (stack, xs))
+    return out, (carries, stack, acc, xs)
+
+
+def _fold_scan_bwd(step, res, ct):
+    carries, stack, acc, xs = res
+    remat = jax.checkpoint(step)  # the recompute, named as the checkpoint's
+
+    def body(state, s):
+        ct, acc = state
+        c, lp, x, i = s
+        _, vjp = jax.vjp(lambda c, lp: remat(c, lp, x), c, lp)
+        ct, g = vjp(ct)
+        # acc[i] + g, the operand order of the sum it replaces.  XLA fuses a
+        # one-index scatter-add into the weight-gradient matmul, reading and
+        # writing the slice in place; a dynamic-slice + dynamic-update-slice
+        # leaves the slice's read as a copy of its own
+        return (ct, jax.tree.map(lambda a, g: a.at[i].add(g), acc, g)), None
+
+    n = jax.tree.leaves(stack)[0].shape[0]
+    (ct, acc), _ = jax.lax.scan(body, (ct, acc),
+                                (carries, stack, xs, jnp.arange(n)),
+                                reverse=True)
+    return ct, acc, None, None
+
+
+_fold_scan.defvjp(_fold_scan_fwd, _fold_scan_bwd)
+
+
+def _folds(stack) -> bool:
+    """Whether the scan over ``stack`` folds the gradient sum: only a loop
+    of two or more layers.  XLA removes a one-trip loop, and the sum after
+    it then fuses with the weight gradient already."""
+    return jax.tree.leaves(stack)[0].shape[0] >= 2
+
+
+def grad_fold_mask(cfg: ModelConfig, params):
+    """True on the leaves whose gradient :func:`loss` returns with
+    ``grad_acc`` already added: the stacks of the dense and expert trunks
+    that :func:`_folds`.  The other leaves' sum is left to the caller."""
+    def fill(tree, flag):
+        return jax.tree.map(lambda _: flag, tree)
+
+    mask = fill(params, False)
+    if cfg.family in ("ssm", "hybrid", "audio"):
+        return mask
+    layers = params["layers"]
+    if cfg.num_experts:  # the lead stack, then the super-layers' one scan
+        blocks = {k: v for k, v in layers.items() if k != "lead"}
+        mask["layers"] = {k: fill(v, _folds(v if k == "lead" else blocks))
+                          for k, v in layers.items()}
+    else:
+        mask["layers"] = fill(layers, _folds(layers))
+    return mask
+
+
 def _prefetch_scan(*args, **kwargs):
     # lazy: repro.core's __init__ imports this module, so a top-level
     # import of repro.core.odc would be circular
@@ -263,20 +341,23 @@ def _prefetch_scan(*args, **kwargs):
 
 
 def _forward_dense(cfg, params, batch, caches, cache_index, remat, block_kv,
-                   pxform, prefetch=None):
+                   pxform, prefetch=None, grad_acc=None):
     x = _embed(cfg, params, batch)
     sw = _static_window(cfg)  # hoisted when uniform across layers
     windows = None if sw is not None else _window_schedule(cfg)
     x, new_caches = _dense_trunk(
         cfg, params["layers"], x, batch, caches, cache_index, remat,
-        block_kv, pxform, prefetch, sw, windows)
+        block_kv, pxform, prefetch, sw, windows,
+        acc=None if grad_acc is None else grad_acc["layers"])
     return x, jnp.float32(0.0), new_caches
 
 
 def _dense_trunk(cfg, stack, x, batch, caches, cache_index, remat, block_kv,
-                 pxform, prefetch, sw, windows):
+                 pxform, prefetch, sw, windows, acc=None):
     """A stack of dense blocks, scanned; ``sw`` the static window of every
-    layer, else ``windows`` the per-layer schedule.  Returns (x, caches)."""
+    layer, else ``windows`` the per-layer schedule; ``acc`` the stack's
+    gradient sum to fold in where it :func:`_folds`, or None.  Returns
+    (x, caches)."""
     positions = batch.get("positions")
     segment_ids = batch.get("segment_ids")
 
@@ -308,6 +389,10 @@ def _dense_trunk(cfg, stack, x, batch, caches, cache_index, remat, block_kv,
         )
         return x, cache
 
+    if acc is not None and _folds(stack):  # training: no caches
+        return _fold_scan(
+            lambda x, lp, w: body(x, (lp,) if sw is not None else (lp, w))[0],
+            x, stack, acc, windows), None
     if remat:
         body = jax.checkpoint(body)
     xs = (stack,)
@@ -319,7 +404,7 @@ def _dense_trunk(cfg, stack, x, batch, caches, cache_index, remat, block_kv,
 
 
 def _forward_moe(cfg, params, batch, caches, cache_index, remat, block_kv,
-                 moe_groups, pxform, prefetch=None):
+                 moe_groups, pxform, prefetch=None, grad_acc=None):
     """Returns (x, aux, caches, pairs held)."""
     x = _embed(cfg, params, batch)
     positions = batch.get("positions")
@@ -327,6 +412,8 @@ def _forward_moe(cfg, params, batch, caches, cache_index, remat, block_kv,
     P = cfg.moe_period
     blocks = dict(params["layers"])
     lead = blocks.pop("lead", None)
+    acc = None if grad_acc is None else dict(grad_acc["layers"])
+    lead_acc = None if acc is None else acc.pop("lead", None)
     lead_cache = None
     if caches is not None and "lead" in caches:
         caches = dict(caches)
@@ -334,7 +421,7 @@ def _forward_moe(cfg, params, batch, caches, cache_index, remat, block_kv,
     if lead is not None:
         x, lead_cache = _dense_trunk(
             cfg, lead, x, batch, lead_cache, cache_index, remat, block_kv,
-            pxform, prefetch, 0, None)
+            pxform, prefetch, 0, None, acc=lead_acc)
     zero = jnp.float32(0.0)
 
     if prefetch is not None:
@@ -394,6 +481,10 @@ def _forward_moe(cfg, params, batch, caches, cache_index, remat, block_kv,
             new_cache["moe"] = moe_cache
         return (x, aux + aux_l, pairs + pairs_l), new_cache
 
+    if acc is not None and _folds(blocks):  # training: no caches
+        x, aux, pairs = _fold_scan(lambda c, lp, _: body(c, lp)[0],
+                                   (x, zero, zero), blocks, acc, None)
+        return x, aux, None, pairs
     if remat:
         body = jax.checkpoint(body)
     xs = blocks if caches is None else (blocks, caches)
@@ -634,7 +725,7 @@ def apply(cfg: ModelConfig, params, batch, *, caches=None, cache_index=None,
 
 
 def _apply(cfg, params, batch, *, caches, cache_index, remat, block_kv,
-           moe_groups, pxform, prefetch, last_only):
+           moe_groups, pxform, prefetch, last_only, grad_acc=None):
     """:func:`apply`, plus the expert pairs computed here (MoE family)."""
     if pxform is None:
         pxform = lambda t: t
@@ -655,9 +746,9 @@ def _apply(cfg, params, batch, *, caches, cache_index, remat, block_kv,
     elif fam == "audio":
         x, aux, new_caches = _forward_audio(cfg, params, batch, caches, cache_index, remat, block_kv, pxform, prefetch)
     elif cfg.num_experts:
-        x, aux, new_caches, pairs = _forward_moe(cfg, params, batch, caches, cache_index, remat, block_kv, moe_groups, pxform, prefetch)
+        x, aux, new_caches, pairs = _forward_moe(cfg, params, batch, caches, cache_index, remat, block_kv, moe_groups, pxform, prefetch, grad_acc)
     else:
-        x, aux, new_caches = _forward_dense(cfg, params, batch, caches, cache_index, remat, block_kv, pxform, prefetch)
+        x, aux, new_caches = _forward_dense(cfg, params, batch, caches, cache_index, remat, block_kv, pxform, prefetch, grad_acc)
     if last_only:
         x = x[:, -1:]
     return _logits(cfg, params, x), aux, new_caches, pairs
@@ -665,7 +756,7 @@ def _apply(cfg, params, batch, *, caches, cache_index, remat, block_kv,
 
 def loss(cfg: ModelConfig, params, batch, *, remat: bool = False,
          block_kv: int = 512, moe_groups: int = 0, pxform=None,
-         prefetch=None, reduction: str = "mean"):
+         prefetch=None, reduction: str = "mean", grad_acc=None):
     """Weighted token cross-entropy (weights = loss_mask; supports GRPO-style
     advantage weighting by passing signed weights).
 
@@ -676,11 +767,18 @@ def loss(cfg: ModelConfig, params, batch, *, remat: bool = False,
     ``moe_aux_per_sequence`` a sum over samples (``moe.
     sequence_balance_loss``), added to the summed cross-entropy.  MoE
     configs add ``moe_pairs_held`` to the metrics: the (token, expert)
-    pairs the held experts computed, summed over layers."""
+    pairs the held experts computed, summed over layers.
+
+    grad_acc: None, or a running gradient sum shaped as ``params``; the
+    gradient of each leaf :func:`grad_fold_mask` marks then comes back
+    with its sum added, inside the layer scan's backward."""
+    if grad_acc is not None and (not remat or prefetch is not None):
+        raise ValueError("grad_acc folds into the checkpointed layer scans; "
+                         "it needs remat=True and no prefetch")
     logits, aux, _, pairs = _apply(
         cfg, params, batch, caches=None, cache_index=None, remat=remat,
         block_kv=block_kv, moe_groups=moe_groups, pxform=pxform,
-        prefetch=prefetch, last_only=False)
+        prefetch=prefetch, last_only=False, grad_acc=grad_acc)
     targets = batch["targets"]
     mask = batch.get("loss_mask")
     if mask is None:

@@ -170,6 +170,73 @@ def test_minibatch_step_recomputes_only_the_layers(step_hlo):
         assert own[f"backward/{b}"] == 2 * own[f"forward/{b}"]
 
 
+def _fusions(hlo):
+    """(output shape, op_name, fused computation's lines) of every fusion
+    of an HLO text."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"^(?:ENTRY )?%?([\w.-]+) .*\{$", line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    out = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (\w+\[[\d,]*\])\S* fusion\(.*"
+                     r"calls=%([\w.-]+)", line)
+        if m:
+            op = re.search(r'op_name="([^"]*)"', line)
+            out.append((m.group(1), op.group(1) if op else "",
+                        comps[m.group(2)]))
+    return out
+
+
+def _stacked_layer_shapes():
+    """The reduced qwen-1.5b step's stacked layer leaves, as HLO shapes:
+    {shape: leaves}, and the matmul weights' among them."""
+    from repro.configs import get_reduced
+    from repro.models import transformer as T
+
+    params = jax.eval_shape(lambda: T.init_params(
+        get_reduced("qwen-1.5b"), jax.random.PRNGKey(0)))
+    shapes = [f"f32[{','.join(map(str, x.shape))}]"
+              for x in jax.tree.leaves(params["layers"])]
+    weights = [s for s, x in zip(shapes, jax.tree.leaves(params["layers"]))
+               if x.ndim == 3]
+    return set(shapes), weights
+
+
+def test_no_pass_over_a_stacked_gradient_after_the_layer_loop(step_hlo):
+    """The minibatch loop's gradient sum is folded into the layer loop's
+    backward: no fusion of the microbatch loop adds a whole stacked layer
+    gradient to the running sum after the layer loop has written it."""
+    stacked, _ = _stacked_layer_shapes()
+    outer = [(shape, op) for shape, op, _ in _fusions(step_hlo)
+             if shape in stacked and op.endswith("while/body/closed_call/add")]
+    assert not outer
+
+
+def test_each_stacked_weight_gradient_adds_into_the_running_sum(step_hlo):
+    """Each stacked weight's gradient matmul in the layer loop's backward
+    writes the running sum's slice plus its gradient in place: the fusion
+    reads the slice of the stack it updates, adds the matmul, and writes
+    the slice back."""
+    _, weights = _stacked_layer_shapes()
+    folded = []
+    for shape, op, body in _fusions(step_hlo):
+        text = "\n".join(body)
+        if (shape in weights and "transpose(" in op
+                and " convolution(" in text):
+            assert re.search(r" dynamic-slice\(%param_0", text), op
+            assert " add(" in text, op
+            root = next(x for x in body if x.lstrip().startswith("ROOT "))
+            assert " dynamic-update-slice(%param_0" in root, op
+            folded.append(shape)
+    assert sorted(folded) == sorted(weights)
+
+
 def test_expert_layer_kernels_compile_under_their_scope(one_chip,
                                                         monkeypatch):
     """DeepSeek-V2-Lite's held expert layer at real widths (8 held of 64,

@@ -96,8 +96,12 @@ def test_both_levels_of_remat_are_named(one_device_step):
     level of remat left.  The minibatch loop runs a microbatch's forward
     once, right before its backward, so the head and the loss are never
     recomputed."""
+    # the layer loop inside the backward of the microbatch loop, where the
+    # folded gradient sum's backward (``transformer._fold_scan``) runs the
+    # layer's checkpoint
     layer_remat = ("jit(step)/while/body/closed_call/transpose(jvp())/"
-                   "while/body/closed_call/checkpoint/rematted_computation/")
+                   "while/body/closed_call/transpose(jvp(jvp()))/"
+                   "checkpoint/rematted_computation/")
     blocks = set()
     for _, p in one_device_step:
         # reduction bodies carry paths relative to their reduce
